@@ -240,6 +240,15 @@ def bench_compress_suite() -> dict:
     return out
 
 
+def _cpu_child_env(repo: str) -> dict:
+    """Environment for a ``launch.compress`` child of this (JAX-holding)
+    process.  The child measures host RSS and a metadata-only plan, which
+    need no chip, and a chip belongs to one process: this parent already
+    holds it, so the child runs on the CPU."""
+    return dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+                JAX_PLATFORMS="cpu")
+
+
 def _bench_streaming_row() -> dict:
     """Streaming execute under a 64 MiB host budget, run as a fresh
     subprocess of the CLI: ru_maxrss is a process-lifetime high-water mark,
@@ -252,7 +261,7 @@ def _bench_streaming_row() -> dict:
     import tempfile
 
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    env = _cpu_child_env(repo)
     env.pop("REPRO_STREAM_KILL_AFTER", None)
     with tempfile.TemporaryDirectory() as td:
         proc = subprocess.run(
@@ -292,7 +301,7 @@ def _bench_plan405b_row() -> dict:
     import sys
 
     repo = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    env = _cpu_child_env(repo)
     proc = subprocess.run(
         [sys.executable, "-m", "repro.launch.compress",
          "--arch", "llama3-405b", "--streaming", "--metadata-only",
@@ -358,7 +367,7 @@ def _bench_probe_row(values, key) -> dict:
 def bench_bitlinear_suite(fast: bool = False) -> dict:
     """Fused bitlinear schedule microbench: per (geometry, T) case, time the
     unpack+einsum oracle against every bitlinear schedule lane (pallas
-    grid / decode / stream under the current pallas mode, the jnp
+    grid / decode under the current pallas mode, the jnp
     formulations) plus the autotuned best (kernels/autotune.py).  Rows
     carry ``device``/``pallas_mode``, so a compiled-mode (TPU/GPU) lane
     lands as new rows without schema changes.  Writes BENCH_bitlinear.json.
@@ -395,7 +404,6 @@ def bench_bitlinear_suite(fast: bool = False) -> dict:
     lanes = {
         "pallas_grid": autotune.Schedule("grid", "unpack"),
         "pallas_decode": autotune.Schedule("decode", "bitplane"),
-        "pallas_stream": autotune.Schedule("stream", "unpack"),
         "jnp_dot": autotune.Schedule("jnp", "dot"),
         "jnp_bitplane": autotune.Schedule("jnp", "bitplane"),
     }
@@ -406,7 +414,6 @@ def bench_bitlinear_suite(fast: bool = False) -> dict:
             x, mp, C = operands(E, n_r, n_c, tn, K, td, T)
             w = {"m_packed": mp, "C": C}
             call = bl.bitlinear_grouped if E else bl.bitlinear
-            valid = bl.GROUPED_MODES if E else bl.MODES
             row = {
                 "kind": "grouped" if E else "2d", "case": case,
                 "E": E, "n_r": n_r, "n_c": n_c, "tn": tn, "K": K, "td": td,
@@ -419,11 +426,10 @@ def bench_bitlinear_suite(fast: bool = False) -> dict:
             )
             fns = {"einsum": jax.jit(lambda x: ein_fn(x, w))}
             for lane, s in lanes.items():
-                if s.mode in valid:
-                    fns[lane] = jax.jit(
-                        lambda x, s=s: call(x, mp, C, interpret=interpret,
-                                            **s.kwargs())
-                    )
+                fns[lane] = jax.jit(
+                    lambda x, s=s: call(x, mp, C, interpret=interpret,
+                                        **s.kwargs())
+                )
             fns["tuned"] = jax.jit(
                 lambda x: call(x, mp, C, interpret=interpret, **best.kwargs())
             )

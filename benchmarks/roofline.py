@@ -20,8 +20,7 @@ import argparse
 import json
 import traceback
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHITECTURES, SHAPES, get_config, shape_cells
 from repro.launch.costing import cost_cell
 
@@ -80,6 +79,7 @@ def main():
     ap.add_argument("--out", default="experiments/roofline")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cells = (
         [(a, s) for a in ARCHITECTURES for s in shape_cells(a)]

@@ -33,7 +33,7 @@ from repro.configs import get_config, reduced_for_smoke
 from repro.configs.base import ParallelConfig, ShapeConfig
 from repro.data.pipeline import make_pipeline
 from repro.distributed.sharding import activation_rules
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim import warmup_cosine
 from repro.serving.engine import Engine
 from repro.training import init_train_state, make_train_step, state_shardings
@@ -59,7 +59,7 @@ def main():
     sh = state_shardings(cfg, pcfg, mesh)
     fn = make_train_step(cfg, pcfg, warmup_cosine(3e-3, 10, args.train_steps))
     pipe = make_pipeline(cfg, shape, mesh)
-    with set_mesh(mesh), activation_rules(pcfg, mesh):
+    with jax.set_mesh(mesh), activation_rules(pcfg, mesh):
         jstep = jax.jit(fn, in_shardings=(sh, None), out_shardings=(sh, None),
                         donate_argnums=0)
         for i in range(args.train_steps):

@@ -22,7 +22,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import make_pipeline
 from repro.distributed.fault_tolerance import StepTimer
 from repro.distributed.sharding import activation_rules
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim import warmup_cosine
 from repro.training import init_train_state, make_train_step, state_shardings
 
@@ -63,7 +63,7 @@ def main():
     pipe = make_pipeline(cfg, shape, mesh)
     timer = StepTimer()
 
-    with set_mesh(mesh), activation_rules(pcfg, mesh):
+    with jax.set_mesh(mesh), activation_rules(pcfg, mesh):
         jstep = jax.jit(step_fn, in_shardings=(sh, None),
                         out_shardings=(sh, None), donate_argnums=0)
         step = int(state.step)

@@ -54,6 +54,7 @@ from repro.compression.artifact import CompressionArtifact
 from repro.compression.execute import (
     _tensor_keys,
     _tensor_tiles,
+    auto_decompose_chunk,
     auto_pool_chunk,
     tile_residuals,
 )
@@ -312,7 +313,7 @@ def delta_recompress(
         total = int(tiles.shape[0])
         chunk = (
             auto_pool_chunk(total, tn, K, bbo_iters)
-            if method == "bbo" else total
+            if method == "bbo" else auto_decompose_chunk(total, tn, td)
         )
         # distinct fold ("delt") from execute's pool fold: a delta solve of
         # a bbo pool is a different lock-step run, not a replay

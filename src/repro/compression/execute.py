@@ -44,6 +44,8 @@ __all__ = [
     "execute_plan",
     "surrogate_tile_bytes",
     "auto_pool_chunk",
+    "decompose_tile_bytes",
+    "auto_decompose_chunk",
     "tile_residuals",
     "POOL_BUDGET_ENV",
 ]
@@ -60,6 +62,9 @@ _DEFAULT_POOL_BUDGET = 64 << 20
 _MIN_BBO_CHUNK = 64      # stay in the >=64-problem regime the batched
                          # Ising backends want (BENCH_ising.json)
 _MAX_POOL_CHUNK = 4096   # legacy hard bound
+# Budget for one greedy/alternating/int8 chunk's device working set: a
+# sixteenth of a 16 GB chip, beside the model it compresses.
+_DECOMPOSE_BUDGET = 1 << 30
 
 
 def surrogate_tile_bytes(tile_n: int, K: int, bbo_iters: int) -> int:
@@ -73,6 +78,16 @@ def surrogate_tile_bytes(tile_n: int, K: int, bbo_iters: int) -> int:
     p = feat.num_features(n)
     max_points = n + max(bbo_iters, 1)
     return 4 * (3 * p * p + 4 * p) + 4 * max_points * (n + 2)
+
+
+def _even_chunk(total_tiles: int, cap: int) -> int:
+    """``total_tiles`` when it fits ``cap``, else an even split into
+    ceil(total / cap) chunks, so at most two distinct chunk shapes
+    compile."""
+    if total_tiles <= cap:
+        return total_tiles
+    n_chunks = -(-total_tiles // cap)
+    return -(-total_tiles // n_chunks)
 
 
 def auto_pool_chunk(
@@ -92,10 +107,30 @@ def auto_pool_chunk(
         )
     per_tile = surrogate_tile_bytes(tile_n, K, bbo_iters)
     cap = max(_MIN_BBO_CHUNK, min(_MAX_POOL_CHUNK, budget_bytes // per_tile))
-    if total_tiles <= cap:
-        return total_tiles
-    n_chunks = -(-total_tiles // cap)
-    return -(-total_tiles // n_chunks)
+    return _even_chunk(total_tiles, cap)
+
+
+def decompose_tile_bytes(tile_n: int, tile_d: int) -> int:
+    """Per-tile working set of a greedy/alternating/int8 pool chunk, as
+    four f32 tiles — the memory model behind those pools'
+    ``max_pool_tiles="auto"`` chunks.  (XLA's temporaries for an
+    alternating chunk at 32x128, compiled for a TPU v5e, come to ~2.1 f32
+    tiles per tile; the bf16 input, outputs and headroom make up the rest.)"""
+    return 4 * 4 * tile_n * tile_d
+
+
+def auto_decompose_chunk(
+    total_tiles: int,
+    tile_n: int,
+    tile_d: int,
+    budget_bytes: int = _DECOMPOSE_BUDGET,
+) -> int:
+    """Chunk for one greedy/alternating/int8 pool.  These pools run no
+    Ising solve, so the only bound is device memory: a whole-model pool
+    (granite-moe-1b-a400m at tile 32x128: ~313k tiles, 5 GB of f32 tiles
+    before temporaries) would not fit a 16 GB chip in one batch."""
+    per_tile = decompose_tile_bytes(tile_n, tile_d)
+    return _even_chunk(total_tiles, max(1, budget_bytes // per_tile))
 
 
 @jax.jit
@@ -241,9 +276,9 @@ def execute_plan(
     O(tiles * num_features^2) — chunking keeps memory bounded while every
     chunk is still a large batch.  The default "auto" derives each BBO
     pool's chunk from the surrogate-memory model (:func:`auto_pool_chunk`,
-    budget via ``REPRO_POOL_BUDGET_BYTES``) and leaves the cheap
-    greedy/alternating pools unchunked; an int pins the bound for every
-    pool; None disables chunking.  Chunking never changes
+    budget via ``REPRO_POOL_BUDGET_BYTES``) and every other pool's from its
+    decomposition working set (:func:`auto_decompose_chunk`, a device-memory
+    bound); an int pins the bound for every pool; None disables chunking.  Chunking never changes
     greedy/alternating results (per-tile keys); BBO results depend on the
     chunk boundaries (each chunk is its own lock-step run).
     The artifact's manifest records per-tensor geometry/bytes/errors and
@@ -267,7 +302,7 @@ def execute_plan(
         if max_pool_tiles == "auto":
             chunk = (
                 auto_pool_chunk(total, tn, K, bbo_iters)
-                if method == "bbo" else total
+                if method == "bbo" else auto_decompose_chunk(total, tn, td)
             )
         else:
             chunk = total if not max_pool_tiles else min(total, max_pool_tiles)

@@ -1,8 +1,8 @@
 """Kernel schedule autotuner for the fused bitlinear path.
 
 The bitlinear kernel (``repro.kernels.bitlinear``) exposes a small schedule
-space — mode (grid / decode / stream / jnp), bit algebra (unpack /
-bitplane / dot), token block ``block_t`` and reduction chunking
+space — mode (grid / decode / jnp), bit algebra (unpack / bitplane /
+dot), token block ``block_t`` and reduction chunking
 ``r_chunk`` — and the best point depends on (tile geometry, token count,
 dtype, device, pallas execution mode) in ways a static heuristic can't
 rank: on TPU the decode fast path wins until the column working set
@@ -105,7 +105,9 @@ class Schedule:
 
 
 def device_kind() -> str:
-    return jax.devices()[0].platform
+    """The chip as JAX names it (``jax.devices()[0].device_kind``, e.g.
+    "TPU v5 lite"), so tuned keys separate chip generations."""
+    return jax.devices()[0].device_kind
 
 
 def pallas_mode() -> str:
@@ -322,13 +324,16 @@ def candidates(
     c_itemsize: int,
 ) -> list[Schedule]:
     """The schedule points :func:`tune` times for one call signature.
-    Invalid points (decode working set over budget, r_chunk not dividing
-    n_r) are filtered here so the search never times a schedule serving
-    would refuse."""
+    Invalid points (decode working set over budget) are filtered here, and
+    grid chunks are deduplicated by the chunk the kernel actually runs
+    (``bitlinear._resolve_r_chunk`` raises a chunk to Mosaic's 128-lane x
+    block), so the search never times a schedule serving would refuse or
+    the same kernel twice."""
     out = [Schedule(mode="jnp", math=m) for m in ("unpack", "dot", "bitplane")]
-    r_chunks = sorted({_largest_divisor_leq(n_r, c) for c in (1, 2, 4, 8)})
+    r_chunks = sorted({
+        _bl._resolve_r_chunk(n_r, tn, c) for c in (1, 2, 4, 8)
+    })
     block_ts = [128] if T <= 64 else [64, 128, 256]
-    grouped = kind == "bitlinear_grouped"
     for math in _bl.MATHS:
         for bt in block_ts:
             for rc in r_chunks:
@@ -340,9 +345,6 @@ def candidates(
             _bl._vmem_budget(None),
         ):
             out.append(Schedule("decode", math))
-        if not grouped:
-            for rc in r_chunks[:2]:
-                out.append(Schedule("stream", math, 128, rc))
     return out
 
 
@@ -372,8 +374,9 @@ def tune(
 ) -> tuple[Schedule, list[dict]]:
     """Timed best-of-N search over the candidate schedules for one concrete
     call; returns (best, trials).  Grouped operands (x.ndim == 3) route to
-    ``bitlinear_grouped``.  Schedules that fail to lower (e.g. an
-    unsupported mode on this backend) are skipped, not fatal."""
+    ``bitlinear_grouped``.  Every schedule must name a kernel mode; one
+    that fails to lower raises — a tuned table never records a fallback
+    in place of the kernel it could not compile."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     grouped = x.ndim == 3
@@ -391,31 +394,28 @@ def tune(
             kind, n_r=n_r, n_c=n_c, tn=tn, kb=kb, K=K, td=td, T=T,
             x_itemsize=x.dtype.itemsize, c_itemsize=C.dtype.itemsize,
         )
+    schedules = list(schedules)
+    unknown = [s for s in schedules if s.mode not in _bl.MODES]
+    if unknown:
+        raise ValueError(f"unknown bitlinear mode(s) for {kind}: {unknown}")
     call = _bl.bitlinear_grouped if grouped else _bl.bitlinear
-    valid_modes = _bl.GROUPED_MODES if grouped else _bl.MODES
 
     trials = []
     best: Schedule | None = None
     best_t = float("inf")
     for s in schedules:
-        if s.mode not in valid_modes:
-            continue
-        try:
-            # time a jitted closure: serving calls the kernel from inside a
-            # jitted step, so the python wrapper's static dispatch must not
-            # count against fast schedules
-            jfn = jax.jit(
-                functools.partial(call, interpret=interpret, **s.kwargs())
-            )
-            dt = _bench_once(lambda: jfn(x, m_packed, C), repeats, iters)
-        except Exception as err:  # unsupported lowering on this backend
-            trials.append({"schedule": s.to_dict(), "error": str(err)[:200]})
-            continue
+        # time a jitted closure: serving calls the kernel from inside a
+        # jitted step, so the python wrapper's static dispatch must not
+        # count against fast schedules
+        jfn = jax.jit(
+            functools.partial(call, interpret=interpret, **s.kwargs())
+        )
+        dt = _bench_once(lambda: jfn(x, m_packed, C), repeats, iters)
         trials.append({"schedule": s.to_dict(), "seconds": dt})
         if dt < best_t:
             best, best_t = s, dt
     if best is None:
-        raise RuntimeError(f"no bitlinear schedule lowered for {kind}")
+        raise ValueError(f"no bitlinear schedule to time for {kind}")
     return best, trials
 
 
@@ -495,9 +495,7 @@ def tune_artifact(
             _CACHE[key] = best
             n_tuned += 1
             if verbose:
-                dt = min(
-                    t["seconds"] for t in trials if "seconds" in t
-                )
+                dt = min(t["seconds"] for t in trials)
                 print(
                     f"[autotune] {key} -> {best.mode}/{best.math}"
                     f" bt={best.block_t} rc={best.r_chunk}"

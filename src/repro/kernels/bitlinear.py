@@ -22,11 +22,6 @@ Schedules (``mode``) behind one entry point — docs/kernels.md:
     r-reduction runs inside a single kernel invocation with C resident in
     VMEM, so every M/C byte is read from HBM exactly once per step and z
     never leaves registers.
-  * stream, grid (c,): M and C stay in HBM (``memory_space=ANY``) and the
-    kernel double-buffers them into a 2-slot VMEM scratch with explicit
-    async copies — the DMA for r-block i+1 is issued before the MXU
-    consumes block i.  Covers decode-shaped T whose column working set is
-    too big for the decode path's all-resident VMEM budget.
   * jnp: no pallas_call — the same fused math as straight-line XLA ops.
     The serving schedule for non-TPU backends, where Pallas interpret-mode
     overhead (~50-100us per call) dwarfs these skinny matmuls; on TPU it
@@ -34,22 +29,25 @@ Schedules (``mode``) behind one entry point — docs/kernels.md:
 
 Bit algebra (``math``):
 
-  * unpack: M is unpacked to {-1,+1} staged through **int8** — the
-    shift/and/reshape chain materialises 1-byte elements, not f32 (4x
-    smaller unpack working set in VMEM/VREGs), and widens to the activation
-    dtype only at the MXU operand.  Integer activations keep the operand
-    int8 and accumulate via ``preferred_element_type=int32``.
+  * unpack: M is unpacked to a {-1,+1} **int8** plane by a shift/and chain
+    built from broadcasts alone (no sublane-to-lane reshape, which Mosaic
+    refuses), and widens to the activation dtype only at the MXU operand.
+    Integer activations keep the operand int8 and accumulate via
+    ``preferred_element_type=int32``.
   * bitplane: M = 2*B - 1 with B in {0,1}, so z = x @ M = 2*(x @ B) - s
     where s = rowsum(x) per r tile.  The affine correction moves from the
     (tn, K) M tile to the (bt, K) z block — cheaper whenever bt < tn (the
     decode regime) — and B feeds the MXU as the raw unpacked bit, one
     int8->dtype widening and no elementwise 2b-1 on the M side at all.
 
-MXU alignment: bt and td should be multiples of 128 on real hardware;
-K and tn are tile-level and may be small.  Schedule selection per
-(geometry, T, dtype, device) lives in ``repro.kernels.autotune``; ``mode=
-"auto"`` here keeps the static pallas heuristic (decode when it fits,
-else grid).
+Block alignment: Mosaic takes a block whose last two dims are multiples of
+(8, 128) or whole array dims.  So the grid schedule's x block spans
+``r_chunk * tn`` lanes that are a multiple of 128 or all of d_in
+(:func:`_resolve_r_chunk` raises the chunk until they do), and bt and td
+should be multiples of 128 for the MXU; K and tn are tile-level and may be
+small.  Schedule selection per (geometry, T, dtype, device) lives in
+``repro.kernels.autotune``; ``mode="auto"`` here keeps the static pallas
+heuristic (decode when it fits, else grid).
 """
 
 from __future__ import annotations
@@ -62,12 +60,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+__all__ = ["bitlinear", "bitlinear_grouped", "MODES", "MATHS"]
 
-__all__ = ["bitlinear", "bitlinear_grouped", "MODES", "GROUPED_MODES", "MATHS"]
-
-MODES = ("auto", "grid", "decode", "stream", "jnp")
-GROUPED_MODES = ("auto", "grid", "decode", "jnp")
+MODES = ("auto", "grid", "decode", "jnp")
 MATHS = ("unpack", "bitplane")
 
 # VMEM budget for the decode fast path (x block + all M/C tiles of one
@@ -77,7 +72,7 @@ MATHS = ("unpack", "bitplane")
 _DECODE_VMEM_BYTES = 4 * 2**20
 _DECODE_VMEM_ENV = "REPRO_DECODE_VMEM_BYTES"
 # Bound on the python-unrolled r-reduction of the decode kernel (compile
-# size control; past this the grid/stream schedules win anyway).
+# size control; past this the grid schedule wins anyway).
 _DECODE_MAX_R = 256
 
 
@@ -94,12 +89,30 @@ def _vmem_budget(override: int | None) -> int:
 
 def _unpack_i8(mp, K: int, signed: bool):
     """uint8 (tn, kb) -> int8 (tn, K): {0,1} bits, or {-1,+1} when signed.
-    Every intermediate is 1 byte wide — the unpack chain never materialises
-    a float M."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint8, (1, 1, 8), 2)
-    bits = ((mp[:, :, None] >> shifts) & jnp.uint8(1)).astype(jnp.int8)
-    b = bits.reshape(mp.shape[0], mp.shape[1] * 8)[:, :K]
-    return 2 * b - 1 if signed else b
+
+    Column k is bit k % 8 of byte k // 8.  Each byte column is widened to
+    int32 and shifted against a lane iota, so the (tn, K) plane is built by
+    broadcasting alone: a (tn, kb, 8) -> (tn, 8 kb) reshape would move data
+    from sublanes to lanes, which Mosaic refuses."""
+    tn, kb = mp.shape
+    words = mp.astype(jnp.int32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+    b = jnp.zeros((tn, K), jnp.int32)
+    for j in range(kb):
+        shift = jnp.clip(col - 8 * j, 0, 7)
+        b = jnp.where(col // 8 == j, (words[:, j:j + 1] >> shift) & 1, b)
+    b = 2 * b - 1 if signed else b
+    return b.astype(jnp.int8)
+
+
+def _mxu(a, b, acc_t=jnp.float32):
+    """a @ b on the MXU, accumulated in ``acc_t``.  f32 operands contract
+    at HIGHEST precision, not rounded to bf16 (the TPU default), so an f32
+    model matches its XLA reference instead of drifting from it; Mosaic
+    takes no precision for bf16 or int8 operands, which are exact anyway."""
+    f32 = a.dtype == jnp.float32
+    precision = jax.lax.Precision.HIGHEST if f32 else None
+    return jnp.dot(a, b, precision=precision, preferred_element_type=acc_t)
 
 
 def _z_block(x, mp, *, K: int, math: str):
@@ -111,23 +124,19 @@ def _z_block(x, mp, *, K: int, math: str):
     acc_t = jnp.int32 if integer else jnp.float32
     if math == "bitplane":
         b = _unpack_i8(mp, K, signed=False)
-        op = b if integer else b.astype(x.dtype)
-        zb = jnp.dot(x, op, preferred_element_type=acc_t)
+        zb = _mxu(x, b if integer else b.astype(x.dtype), acc_t)
         s = jnp.sum(x.astype(acc_t), axis=-1, keepdims=True)
         return 2 * zb - s
     m = _unpack_i8(mp, K, signed=True)
-    op = m if integer else m.astype(x.dtype)
-    return jnp.dot(x, op, preferred_element_type=acc_t)
+    return _mxu(x, m if integer else m.astype(x.dtype), acc_t)
 
 
-def _accumulate_block(x, mp, c, acc_ref, *, K: int, math: str):
-    """Shared r-step body of the grid schedules: one z = x @ M block through
-    the selected bit algebra, then the small real factor, accumulated into
-    the f32 VMEM scratch."""
+def _tile_out(x, mp, c, *, K: int, math: str):
+    """One r tile's f32 contribution (x @ M) @ C: z through the selected
+    bit algebra, then the small real factor.  Every schedule accumulates
+    these over r."""
     z = _z_block(x, mp, K=K, math=math)                           # (bt, K)
-    acc_ref[...] += jnp.dot(
-        z.astype(c.dtype), c, preferred_element_type=jnp.float32
-    )
+    return _mxu(z.astype(c.dtype), c)
 
 
 def _pad_rows(x, T: int, block_t: int):
@@ -157,9 +166,9 @@ def _kernel(x_ref, mp_ref, c_ref, o_ref, acc_ref, *, K, n_rsteps, r_chunk, tn,
     # x (bt, r_chunk*tn), mp (r_chunk, 1, tn, kb) uint8, c (r_chunk, 1, K, td)
     x = x_ref[...]
     for j in range(r_chunk):
-        _accumulate_block(
-            x[:, j * tn:(j + 1) * tn], mp_ref[j, 0], c_ref[j, 0], acc_ref,
-            K=K, math=math,
+        acc_ref[...] += _tile_out(
+            x[:, j * tn:(j + 1) * tn], mp_ref[j, 0], c_ref[j, 0], K=K,
+            math=math,
         )
 
     @pl.when(r == n_rsteps - 1)
@@ -176,10 +185,8 @@ def _decode_kernel(x_ref, mp_ref, c_ref, o_ref, *, K, n_r, tn, math):
     x = x_ref[...]                       # (Tp, d_in)
     acc = jnp.zeros(o_ref.shape, jnp.float32)
     for r in range(n_r):                 # static unroll: z stays in VREGs
-        z = _z_block(x[:, r * tn:(r + 1) * tn], mp_ref[r, 0], K=K, math=math)
-        c = c_ref[r, 0]                  # (K, td), VMEM-resident
-        acc = acc + jnp.dot(z.astype(c.dtype), c,
-                            preferred_element_type=jnp.float32)
+        acc = acc + _tile_out(x[:, r * tn:(r + 1) * tn], mp_ref[r, 0],
+                              c_ref[r, 0], K=K, math=math)  # C in VMEM
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -195,70 +202,6 @@ def _decode_path_ok(Tp, d_in, n_r, tn, kb, K, td, x_itemsize, c_itemsize,
                                                # (int8 plane + MXU operand)
     )
     return n_r <= _DECODE_MAX_R and vmem <= budget
-
-
-# ---------------------------------------------------------------------------
-# stream schedule (double-buffered HBM->VMEM copies of the r blocks)
-# ---------------------------------------------------------------------------
-
-
-def _stream_kernel(x_ref, mp_hbm, c_hbm, o_ref, *, K, n_r, r_chunk, tn, kb,
-                   td, math, c_dtype):
-    n_steps = n_r // r_chunk
-    Tq = x_ref.shape[0]
-
-    def body(mp_buf, c_buf, sem_m, sem_c):
-        def copies(slot, step):
-            lo = step * r_chunk
-            return (
-                pltpu.make_async_copy(
-                    mp_hbm.at[pl.ds(lo, r_chunk)], mp_buf.at[slot],
-                    sem_m.at[slot]),
-                pltpu.make_async_copy(
-                    c_hbm.at[pl.ds(lo, r_chunk)], c_buf.at[slot],
-                    sem_c.at[slot]),
-            )
-
-        dm, dc = copies(0, 0)
-        dm.start()
-        dc.start()
-
-        def step_body(step, acc):
-            slot = jax.lax.rem(step, 2)
-
-            # overlapped copy: issue the DMA for r-block step+1 before the
-            # MXU consumes block ``step``
-            @pl.when(step + 1 < n_steps)
-            def _prefetch():
-                nm, ncpy = copies(1 - slot, step + 1)
-                nm.start()
-                ncpy.start()
-
-            wm, wc = copies(slot, step)
-            wm.wait()
-            wc.wait()
-            for j in range(r_chunk):
-                xs = jax.lax.dynamic_slice(
-                    x_ref[...], (0, (step * r_chunk + j) * tn), (Tq, tn)
-                )
-                z = _z_block(xs, mp_buf[slot, j, 0], K=K, math=math)
-                c = c_buf[slot, j, 0]
-                acc = acc + jnp.dot(z.astype(c.dtype), c,
-                                    preferred_element_type=jnp.float32)
-            return acc
-
-        acc = jax.lax.fori_loop(
-            0, n_steps, step_body, jnp.zeros(o_ref.shape, jnp.float32)
-        )
-        o_ref[...] = acc.astype(o_ref.dtype)
-
-    pl.run_scoped(
-        body,
-        mp_buf=pltpu.VMEM((2, r_chunk, 1, tn, kb), jnp.uint8),
-        c_buf=pltpu.VMEM((2, r_chunk, 1, K, td), c_dtype),
-        sem_m=pltpu.SemaphoreType.DMA((2,)),
-        sem_c=pltpu.SemaphoreType.DMA((2,)),
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -343,12 +286,17 @@ def _jnp_bitlinear_grouped(x, mp, C, math: str):
 # ---------------------------------------------------------------------------
 
 
-def _resolve_r_chunk(n_r: int, r_chunk: int) -> int:
-    """Largest divisor of n_r that is <= the requested chunk."""
-    rc = max(1, min(r_chunk, n_r))
-    while n_r % rc:
-        rc -= 1
-    return rc
+def _resolve_r_chunk(n_r: int, tn: int, r_chunk: int) -> int:
+    """The r tiles one grid step reduces: the largest divisor of n_r that
+    is <= the requested chunk, raised to the next divisor whose x block
+    (r_chunk * tn lanes) is a whole number of 128-lane tiles or all of
+    d_in — the only x blocks Mosaic accepts."""
+    divisors = [d for d in range(1, n_r + 1) if n_r % d == 0]
+    rc = max(d for d in divisors if d <= max(1, r_chunk))
+    return min(
+        d for d in divisors
+        if d >= rc and ((d * tn) % 128 == 0 or d == n_r)
+    )
 
 
 @functools.partial(
@@ -376,42 +324,14 @@ def _bitlinear_jit(x, m_packed, C, block_t, interpret, mode, math, r_chunk):
             ],
             out_specs=pl.BlockSpec((Tp, td), lambda c: (0, c)),
             out_shape=jax.ShapeDtypeStruct((Tp, n_c * td), x.dtype),
-            compiler_params=_compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",),
             ),
             interpret=interpret,
         )(x, m_packed, C)
         return out[:T]
 
-    if mode == "stream":
-        rc = _resolve_r_chunk(n_r, r_chunk)
-        out = pl.pallas_call(
-            functools.partial(
-                _stream_kernel, K=K, n_r=n_r, r_chunk=rc, tn=tn, kb=kb,
-                td=td, math=math, c_dtype=C.dtype,
-            ),
-            grid=(n_c,),
-            in_specs=[
-                pl.BlockSpec((Tp, d_in), lambda c: (0, 0)),
-                pl.BlockSpec(
-                    (n_r, 1, tn, kb), lambda c: (0, c, 0, 0),
-                    memory_space=pltpu.ANY,
-                ),
-                pl.BlockSpec(
-                    (n_r, 1, K, td), lambda c: (0, c, 0, 0),
-                    memory_space=pltpu.ANY,
-                ),
-            ],
-            out_specs=pl.BlockSpec((Tp, td), lambda c: (0, c)),
-            out_shape=jax.ShapeDtypeStruct((Tp, n_c * td), x.dtype),
-            compiler_params=_compat.CompilerParams(
-                dimension_semantics=("parallel",),
-            ),
-            interpret=interpret,
-        )(x, m_packed, C)
-        return out[:T]
-
-    rc = _resolve_r_chunk(n_r, r_chunk)
+    rc = _resolve_r_chunk(n_r, tn, r_chunk)
     n_rsteps = n_r // rc
     grid = (Tp // bt, n_c, n_rsteps)
     out = pl.pallas_call(
@@ -427,7 +347,7 @@ def _bitlinear_jit(x, m_packed, C, block_t, interpret, mode, math, r_chunk):
         out_specs=pl.BlockSpec((bt, td), lambda t, c, r: (t, c)),
         out_shape=jax.ShapeDtypeStruct((Tp, n_c * td), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, td), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -441,7 +361,7 @@ def bitlinear(
     C: jax.Array,        # (r, c, K, td)
     block_t: int = 128,
     interpret: bool = False,
-    mode: str = "auto",  # auto | grid | decode | stream | jnp
+    mode: str = "auto",  # auto | grid | decode | jnp
     math: str = "unpack",  # unpack | bitplane (jnp mode also: dot)
     r_chunk: int = 1,
     vmem_budget: int | None = None,
@@ -489,9 +409,9 @@ def _grouped_kernel(x_ref, mp_ref, c_ref, o_ref, acc_ref, *, K, n_rsteps,
     # same body as _kernel behind the leading expert block dim of 1
     x = x_ref[0]
     for j in range(r_chunk):
-        _accumulate_block(
-            x[:, j * tn:(j + 1) * tn], mp_ref[0, j, 0], c_ref[0, j, 0],
-            acc_ref, K=K, math=math,
+        acc_ref[...] += _tile_out(
+            x[:, j * tn:(j + 1) * tn], mp_ref[0, j, 0], c_ref[0, j, 0], K=K,
+            math=math,
         )
 
     @pl.when(r == n_rsteps - 1)
@@ -507,11 +427,8 @@ def _grouped_decode_kernel(x_ref, mp_ref, c_ref, o_ref, *, K, n_r, tn, math):
     x = x_ref[0]
     acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
     for r in range(n_r):
-        z = _z_block(x[:, r * tn:(r + 1) * tn], mp_ref[0, r, 0], K=K,
-                     math=math)
-        c = c_ref[0, r, 0]
-        acc = acc + jnp.dot(z.astype(c.dtype), c,
-                            preferred_element_type=jnp.float32)
+        acc = acc + _tile_out(x[:, r * tn:(r + 1) * tn], mp_ref[0, r, 0],
+                              c_ref[0, r, 0], K=K, math=math)
     o_ref[0] = acc.astype(o_ref.dtype)
 
 
@@ -545,14 +462,14 @@ def _bitlinear_grouped_jit(x, m_packed, C, block_t, interpret, mode, math,
             ],
             out_specs=pl.BlockSpec((1, Tp, td), lambda e, c: (e, 0, c)),
             out_shape=jax.ShapeDtypeStruct((E, Tp, n_c * td), x.dtype),
-            compiler_params=_compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel"),
             ),
             interpret=interpret,
         )(x, m_packed, C)
         return out[:, :T]
 
-    rc = _resolve_r_chunk(n_r, r_chunk)
+    rc = _resolve_r_chunk(n_r, tn, r_chunk)
     n_rsteps = n_r // rc
     grid = (E, Tp // bt, n_c, n_rsteps)
     out = pl.pallas_call(
@@ -571,7 +488,7 @@ def _bitlinear_grouped_jit(x, m_packed, C, block_t, interpret, mode, math,
         out_specs=pl.BlockSpec((1, bt, td), lambda e, t, c, r: (e, t, c)),
         out_shape=jax.ShapeDtypeStruct((E, Tp, n_c * td), x.dtype),
         scratch_shapes=[pltpu.VMEM((bt, td), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -610,7 +527,7 @@ def bitlinear_grouped(
     Ec, _, _, K, td = C.shape
     assert Em == E and Ec == E, (x.shape, m_packed.shape, C.shape)
     assert n_r * tn == d_in, (m_packed.shape, x.shape)
-    assert mode in GROUPED_MODES, mode
+    assert mode in MODES, mode
     assert math in MATHS + ("dot",), math
 
     if mode == "auto":

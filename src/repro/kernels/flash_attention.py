@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
@@ -110,7 +108,7 @@ def flash_attention(
             pltpu.VMEM((qc, 1), jnp.float32),
             pltpu.VMEM((qc, hd), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

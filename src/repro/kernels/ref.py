@@ -20,6 +20,11 @@ __all__ = [
 ]
 
 
+# The Ising oracles contract at full f32 precision, as the kernels do: the
+# TPU's default would round B to bf16 and the two backends would drift.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _unpack(m_packed: jax.Array, K: int, dtype) -> jax.Array:
     bits = (m_packed[..., None] >> jnp.arange(8, dtype=jnp.uint8)) & 1
     bits = bits.reshape(*m_packed.shape[:-1], m_packed.shape[-1] * 8)[..., :K]
@@ -73,7 +78,7 @@ def sa_sweep_ref(h, B, x0, rand, temps):
 
     def one_chain(x0c, randc):
         x = x0c.astype(jnp.float32)
-        f = hf + 2.0 * Bf @ x
+        f = hf + 2.0 * jnp.matmul(Bf, x, precision=_HIGHEST)
 
         def sweep(carry, su):
             x, f = carry
@@ -94,7 +99,7 @@ def sa_sweep_ref(h, B, x0, rand, temps):
             return (x, f), None
 
         (x, _), _ = jax.lax.scan(sweep, (x, f), (temps, randc))
-        e = x @ hf + x @ (Bf @ x)
+        e = x @ hf + x @ jnp.matmul(Bf, x, precision=_HIGHEST)
         return x, e
 
     return jax.vmap(one_chain)(x0, rand)
@@ -130,7 +135,8 @@ def sqa_sweep_ref(h, B, X0, rand, jperps, temperature=0.05):
     def one_chain(X0c, randc):
         X = X0c.astype(jnp.float32)
         F = hf[None] + 2.0 * jax.lax.dot_general(
-            X, Bf, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            X, Bf, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+            preferred_element_type=jnp.float32,
         )
 
         def sweep(carry, su):
@@ -164,7 +170,9 @@ def sqa_sweep_ref(h, B, X0, rand, jperps, temperature=0.05):
             return (X, F), None
 
         (X, _), _ = jax.lax.scan(sweep, (X, F), (jperps, randc))
-        E = jax.vmap(lambda x: x @ hf + x @ (Bf @ x))(X)
+        E = jax.vmap(
+            lambda x: x @ hf + x @ jnp.matmul(Bf, x, precision=_HIGHEST)
+        )(X)
         return X, E
 
     return jax.vmap(one_chain)(X0, rand)

@@ -10,9 +10,11 @@ kernel and the ref.py oracle share exact values):
     accept iff dE < 0 or u < exp(-dE / temperature)
 
 with F the per-replica local field h + 2 B X_p, maintained incrementally.
-grid = (P,); within a cell the state is X (C, T, n), F (C, T, n) and all
-chains update in lock-step.  Pre-drawn uniforms (P, C, S, T, n) keep the
-kernel bit-exact against ``ref.sqa_sweep_many_ref``.
+grid = (P // bp,), bp as many problems as VMEM holds (``auto_block_p``);
+within a cell each Trotter slice holds X (bp, C, n) and F (bp, C, n), and
+all chains of all problems in the block update in lock-step.  Pre-drawn
+uniforms (P, C, S, T, n) keep the kernel bit-exact against
+``ref.sqa_sweep_many_ref``.
 
 The kernel returns every replica and its Ising energy; the caller
 (``repro.core.ising.solve_many``) reduces best-of over (reads x replicas).
@@ -30,78 +32,63 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.sa_sweep import (
+    _bx, _energies, _pick, _tile_bytes, auto_block_p,
+)
+
 __all__ = ["sqa_sweep_many"]
 
 
-def _quench_chains(h, B, X0, rand_flat, jperps, n_trotter, temperature):
-    """Lock-step PIMC quench of one problem's chains.
+def _sqa_kernel(h_ref, b_ref, x0_ref, rand_ref, jperps_ref, temp_ref, x_ref,
+                e_ref):
+    """Lock-step PIMC quench of a block of problems.
 
-    h (1, n) · B (n, n) · X0 (C, T, n) · rand_flat (C, S*T*n) · jperps (1, S)
-    ->  X (C, T, n), E (C, T).  Pure jnp, traced inside the Pallas kernel.
-    The independent oracle ``ref.sqa_sweep_ref`` consumes the same uniforms
-    in the same (sweep, slice, spin) order — keep the two in lock-step.
+    h (bp, 1, n) · B (bp, n, n) · X0 (bp, T, C, n) · rand (bp, S, T, C, n) ·
+    jperps (1, 1, S) · temperature (1, 1, 1)  ->  X (bp, T, C, n),
+    E (bp, T, C, 1).  The Trotter slices are unrolled (T is static), so
+    slice p and its neighbours are whole (bp, C, n) values; spin ``i`` is a
+    lane-mask pick and row ``i`` of B a dynamic sublane load.  The
+    independent oracle ``ref.sqa_sweep_ref`` consumes the same uniforms in
+    the same (sweep, slice, spin) order — keep the two in lock-step.
     """
-    C, T, n = X0.shape
-    S = jperps.shape[1]
-    X = X0
-    # F[c, p, :] = h + 2 (B @ X[c, p])
-    F = h[None] + 2.0 * jax.lax.dot_general(
-        X, B, (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    h = h_ref[...]
+    jperps = jperps_ref[...]
+    temp = jnp.maximum(temp_ref[...], 1e-12)                      # (1, 1, 1)
+    bp, T, C, n = x0_ref.shape
+    S = jperps.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, n), 2)
+    sweep_lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, S), 2)
+    B = b_ref[...]
+    X = [x0_ref[:, p] for p in range(T)]
+    F = [h + 2.0 * _bx(Xp, B) for Xp in X]
 
     def sweep_body(s, carry):
-        X, F = carry
-        jperp = jax.lax.dynamic_slice(jperps, (0, s), (1, 1))[0, 0]
+        X, F = list(carry[0]), list(carry[1])
+        jperp = _pick(jperps, sweep_lane == s)                    # (1, 1, 1)
+        for p in range(T):
+            x_up, x_dn = X[(p + 1) % T], X[(p - 1) % T]
+            u_p = rand_ref[:, s, p]                               # (bp, C, n)
 
-        def slice_body(p, carry):
-            X, F = carry
-            up = (p + 1) % T
-            dn = (p - 1) % T
-
-            def spin_body(i, carry):
-                X, F = carry
-                xi = jax.lax.dynamic_slice(X, (0, p, i), (C, 1, 1))
-                fi = jax.lax.dynamic_slice(F, (0, p, i), (C, 1, 1))
-                xup = jax.lax.dynamic_slice(X, (0, up, i), (C, 1, 1))
-                xdn = jax.lax.dynamic_slice(X, (0, dn, i), (C, 1, 1))
-                u = jax.lax.dynamic_slice(
-                    rand_flat, (0, (s * T + p) * n + i), (C, 1)
-                )[:, :, None]
-                dE = -2.0 * xi * (fi / n_trotter + jperp * (xup + xdn))
-                accept = (dE < 0.0) | (
-                    u < jnp.exp(-dE / jnp.maximum(temperature, 1e-12))
+            def spin_body(i, carry, x_up=x_up, x_dn=x_dn, u_p=u_p):
+                x, f = carry
+                m = lane == i
+                xi = _pick(x, m)                                  # (bp, C, 1)
+                dE = -2.0 * xi * (
+                    _pick(f, m) / T + jperp * (_pick(x_up, m) + _pick(x_dn, m))
                 )
+                accept = (dE < 0.0) | (_pick(u_p, m) < jnp.exp(-dE / temp))
                 delta = jnp.where(accept, -2.0 * xi, 0.0)
-                bcol = jax.lax.dynamic_slice(B, (i, 0), (1, n))[None]  # (1, 1, n)
-                Fp = jax.lax.dynamic_slice(F, (0, p, 0), (C, 1, n))
-                F = jax.lax.dynamic_update_slice(F, Fp + 2.0 * bcol * delta, (0, p, 0))
-                X = jax.lax.dynamic_update_slice(X, xi + delta, (0, p, i))
-                return X, F
+                brow = b_ref[:, pl.ds(i, 1), :]      # row i == col i, (bp, 1, n)
+                return x + jnp.where(m, delta, 0.0), f + 2.0 * brow * delta
 
-            return jax.lax.fori_loop(0, n, spin_body, (X, F))
+            X[p], F[p] = jax.lax.fori_loop(0, n, spin_body, (X[p], F[p]))
+        return tuple(X), tuple(F)
 
-        return jax.lax.fori_loop(0, T, slice_body, (X, F))
-
-    X, _ = jax.lax.fori_loop(0, S, sweep_body, (X, F))
-    E = jnp.sum(X * h[None], axis=2) + jnp.sum(
-        X
-        * jax.lax.dot_general(
-            X, B, (((2,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ),
-        axis=2,
-    )
-    return X, E
-
-
-def _sqa_kernel(h_ref, b_ref, x0_ref, rand_ref, jperps_ref, temp_ref, x_ref, e_ref):
-    X0 = x0_ref[...][0]          # (C, T, n)
-    rand = rand_ref[...][0]      # (C, S*T*n)
-    T = X0.shape[1]
-    X, E = _quench_chains(
-        h_ref[...], b_ref[...][0], X0, rand, jperps_ref[...], T, temp_ref[0, 0]
-    )
-    x_ref[...] = X[None]
-    e_ref[...] = E[None]
+    X, _ = jax.lax.fori_loop(0, S, sweep_body, (tuple(X), tuple(F)))
+    B = b_ref[...]
+    for p in range(T):
+        x_ref[:, p] = X[p]
+        e_ref[:, p] = _energies(h, X[p], B)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -118,34 +105,40 @@ def sqa_sweep_many(
     Returns (X (P, chains, n_trotter, n), energy (P, chains, n_trotter))."""
     P, C, T, n = X0.shape
     S = jperps.shape[0]
-    rand_flat = rand.astype(jnp.float32).reshape(P, C, S * T * n)
+    per_problem = (
+        _tile_bytes(1, n) + _tile_bytes(n, n) + 2 * _tile_bytes(T, C, n)
+        + _tile_bytes(S, T, C, n) + _tile_bytes(T, C, 1)
+    )
+    bp = auto_block_p(P, per_problem, interpret)
 
     X, E = pl.pallas_call(
         _sqa_kernel,
-        grid=(P,),
+        grid=(P // bp,),
         in_specs=[
-            pl.BlockSpec((1, n), lambda p: (p, 0)),
-            pl.BlockSpec((1, n, n), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1, C, T, n), lambda p: (p, 0, 0, 0)),
-            pl.BlockSpec((1, C, S * T * n), lambda p: (p, 0, 0)),
-            pl.BlockSpec((1, S), lambda p: (0, 0)),
-            pl.BlockSpec((1, 1), lambda p: (0, 0)),
+            pl.BlockSpec((bp, 1, n), lambda p: (p, 0, 0)),
+            pl.BlockSpec((bp, n, n), lambda p: (p, 0, 0)),
+            pl.BlockSpec((bp, T, C, n), lambda p: (p, 0, 0, 0)),
+            pl.BlockSpec((bp, S, T, C, n), lambda p: (p, 0, 0, 0, 0)),
+            pl.BlockSpec((1, 1, S), lambda p: (0, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda p: (0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, C, T, n), lambda p: (p, 0, 0, 0)),
-            pl.BlockSpec((1, C, T), lambda p: (p, 0, 0)),
+            pl.BlockSpec((bp, T, C, n), lambda p: (p, 0, 0, 0)),
+            pl.BlockSpec((bp, T, C, 1), lambda p: (p, 0, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((P, C, T, n), jnp.float32),
-            jax.ShapeDtypeStruct((P, C, T), jnp.float32),
+            jax.ShapeDtypeStruct((P, T, C, n), jnp.float32),
+            jax.ShapeDtypeStruct((P, T, C, 1), jnp.float32),
         ],
         interpret=interpret,
     )(
-        h.astype(jnp.float32),
+        h.astype(jnp.float32)[:, None, :],
         B.astype(jnp.float32),
-        X0.astype(jnp.float32),
-        rand_flat,
-        jperps[None].astype(jnp.float32),
-        jnp.full((1, 1), temperature, jnp.float32),
+        # slice-major state and (sweep, slice)-major uniforms: both become
+        # leading-axis loads in-kernel
+        X0.astype(jnp.float32).transpose(0, 2, 1, 3),
+        rand.astype(jnp.float32).transpose(0, 2, 3, 1, 4),
+        jperps.astype(jnp.float32).reshape(1, 1, S),
+        jnp.full((1, 1, 1), temperature, jnp.float32),
     )
-    return X, E
+    return X.transpose(0, 2, 1, 3), E[..., 0].transpose(0, 2, 1)

@@ -62,6 +62,7 @@ from repro.compression import (
     execute_plan,
     plan_compression,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced_for_smoke
 from repro.checkpoint import checkpointer
 from repro.checkpoint.manager import CheckpointManager
@@ -229,6 +230,35 @@ def run_delta(args, values) -> None:
     print(f"fraction_resolved={d['fraction_resolved']:.4f}")
 
 
+def execute_and_save(plan, values, out_dir: str, *, seed: int = 0,
+                     autotune_kernels: bool = False, verbose: bool = True):
+    """Execute ``plan`` over ``values`` and save the compressed params
+    (checkpoint step 0) with the artifact manifest in ``out_dir`` — the
+    layout ``launch/serve.py`` restores.  ``autotune_kernels`` first
+    probes the kernel schedules for every geometry the manifest can
+    produce and persists the winners into ``manifest["kernel_schedules"]``
+    (probe-then-serve: the engine restores them, serving never re-tunes).
+    Returns (compressed values, artifact, execute seconds)."""
+    t = time.time()
+    cvalues, artifact = execute_plan(
+        plan, values, key=jax.random.PRNGKey(seed), verbose=verbose
+    )
+    jax.block_until_ready(cvalues)
+    dt = time.time() - t
+    if autotune_kernels:
+        from repro.kernels import autotune as kernel_autotune
+
+        t = time.time()
+        table = kernel_autotune.tune_artifact(artifact, verbose=verbose)
+        print(f"[autotune] {len(table['entries'])} kernel schedule(s) in "
+              f"{time.time()-t:.1f}s")
+    path = checkpointer.save(out_dir, 0, {"params": cvalues})
+    mpath = artifact.save(out_dir)
+    print(f"saved compressed params to {path}")
+    print(f"saved compression manifest to {mpath}")
+    return cvalues, artifact, dt
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -307,6 +337,7 @@ def main() -> None:
                     help="drift ratio above which a tile re-solves "
                          "(default 1.25; an unchanged tile sits at 1.0)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.delta_from:
         stray = [
             name for name, val in (
@@ -460,11 +491,10 @@ def main() -> None:
     if args.plan_only:
         return
 
-    t = time.time()
-    cvalues, artifact = execute_plan(
-        plan, values, key=jax.random.PRNGKey(args.seed), verbose=True
+    _, artifact, dt = execute_and_save(
+        plan, values, args.out_dir, seed=args.seed,
+        autotune_kernels=args.autotune_kernels,
     )
-    dt = time.time() - t
     report = artifact.report
     print(f"\n[compress/{policy.method}] {len(report.compressed)} tensors in {dt:.1f}s")
     for path, ob, nb, err in report.compressed:
@@ -481,22 +511,6 @@ def main() -> None:
         over = artifact.total_bytes() > budget_bytes
         print(f"budget: {args.budget_mb:.2f} MiB -> "
               f"{'OVER' if over else 'met'}")
-
-    if args.autotune_kernels:
-        # probe-then-serve: tune the kernel schedule table for every
-        # geometry this manifest can produce and persist it alongside the
-        # compressed checkpoint — Engine restores it, serving never re-tunes
-        from repro.kernels import autotune as kernel_autotune
-
-        t = time.time()
-        table = kernel_autotune.tune_artifact(artifact, verbose=True)
-        print(f"[autotune] {len(table['entries'])} kernel schedule(s) in "
-              f"{time.time()-t:.1f}s")
-
-    path = checkpointer.save(args.out_dir, 0, {"params": cvalues})
-    mpath = artifact.save(args.out_dir)
-    print(f"saved compressed params to {path}")
-    print(f"saved compression manifest to {mpath}")
 
 
 if __name__ == "__main__":

@@ -32,7 +32,7 @@ from repro import roofline
 from repro.configs import SHAPES, get_config
 from repro.distributed import sharding as shd
 from repro.launch.cells import _batch_specs, build_cell
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.presets import parallel_preset
 from repro.models import transformer as tr
 from repro.models.params import split
@@ -183,7 +183,7 @@ def cost_cell(arch: str, shape_name: str, multi_pod: bool = False,
         return out
 
     parts = {}
-    with set_mesh(mesh), shd.activation_rules(pcfg, mesh):
+    with jax.set_mesh(mesh), shd.activation_rules(pcfg, mesh):
         # ---- B: one layer group ----
         if kind == "train":
             fn = jax.grad(group_fwd, argnums=(0, 1) if shared_sds is None else (0, 1, 2))

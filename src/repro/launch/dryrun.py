@@ -31,14 +31,13 @@ import json
 import time
 import traceback
 
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHITECTURES, get_config, shape_cells
 from repro.distributed.sharding import activation_rules
 from repro.launch.cells import build_cell
-from repro.launch.mesh import describe, make_production_mesh, set_mesh
+from repro.launch.mesh import describe, make_production_mesh
 from repro.roofline import collective_bytes, cost_summary, memory_summary
 
 HBM_BYTES = 16 * 1024**3  # TPU v5e
@@ -74,7 +73,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
     else:
         ops.disable_kernels()
     cell = build_cell(arch, shape_name, mesh, artifact=artifact)
-    with set_mesh(mesh), activation_rules(cell.pcfg, mesh):
+    with jax.set_mesh(mesh), activation_rules(cell.pcfg, mesh):
         lowered = jax.jit(
             cell.fn,
             in_shardings=cell.in_shardings,
@@ -138,6 +137,7 @@ def main() -> None:
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--skip-existing", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.all:
         cells = [(a, s) for a in ARCHITECTURES for s in shape_cells(a)]

@@ -13,23 +13,22 @@ all-reduce only crosses pods — DESIGN.md §5).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import axis_types_kw as _axis_kw
-from repro.compat import set_mesh
-
-__all__ = ["make_production_mesh", "make_mesh", "set_mesh", "describe"]
+__all__ = ["make_production_mesh", "make_mesh", "describe"]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes) -> Mesh:
     """Arbitrary mesh for tests/examples (e.g. (1, 1) on one CPU)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kw(len(axes)))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes), axis_types=(AxisType.Auto,) * len(axes)
+    )
 
 
 def describe(mesh: Mesh) -> str:

@@ -23,7 +23,9 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.compression import CompressionArtifact, CompressionPolicy
 from repro.compression import execute_plan, plan_compression
 from repro.configs import get_config, reduced_for_smoke
@@ -31,6 +33,68 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.models import init_model
 from repro.models.params import split
 from repro.serving.engine import Engine
+
+
+def restore_checkpoint(ckpt_dir: str, values):
+    """Restore ``ckpt_dir`` onto the dense ``values`` template.  Returns
+    (values, artifact): a directory holding a compression manifest (as
+    ``launch/compress.py`` writes it) restores through the manifest's
+    template — the manifest, not shape-sniffing, decides which weights are
+    ``{"m_packed", "C"}`` dicts and with what geometry — and returns that
+    artifact; any other checkpoint restores dense with artifact None."""
+    mgr = CheckpointManager(ckpt_dir)
+    if CompressionArtifact.exists(ckpt_dir):
+        # the checkpoint's tree is compressed (and holds params only, as
+        # written by launch/compress.py), so the dense template must be
+        # rewritten before restore
+        artifact = CompressionArtifact.load(ckpt_dir)
+        template = artifact.restore_template(values)
+        step, state = mgr.restore_latest({"params": template})
+        if state is not None:
+            t = artifact.manifest["totals"]
+            print(f"[restore] step {step} (compressed: "
+                  f"{len(artifact.manifest['tensors'])} tensors, "
+                  f"x{t['ratio']:.2f})")
+            return state["params"], artifact
+        # manifest without a restorable step: serve the dense init rather
+        # than crashing manifest validation against it
+        print(f"[restore] {ckpt_dir}: manifest present but no checkpoint "
+              "step; serving dense init")
+        return values, None
+    step, state = mgr.restore_latest(
+        {"step": jnp.zeros((), jnp.int32), "params": values, "opt": None}
+    )
+    if state is not None:
+        print(f"[restore] step {step}")
+        values = state["params"]
+    return values, None
+
+
+def serve_load_curve(eng: Engine, prompts, *, max_tokens: int, rates,
+                     num_slots: int = 4, page_size: int = 16):
+    """Serve ``prompts`` through the continuous-batching tier once per
+    arrival rate in ``rates``: ``Scheduler`` over paged KV, the async
+    ``ServeFrontend``, Poisson arrivals from ``run_load``.  Every prefill
+    length and the decode step are traced before the first rate.  Yields
+    one ``LoadResult`` per rate."""
+    from repro.serving import Scheduler, ServeFrontend, run_load
+
+    max_len = max(len(p) for p in prompts) + max_tokens
+    page = min(page_size, max_len)
+    while max_len % page != 0:
+        page //= 2
+    sched = Scheduler(eng, num_slots=num_slots, page_size=page,
+                      max_len=max_len)
+    lens = sorted({len(p) for p in prompts})
+    # warm-up traces every prefill bucket + the decode step
+    sched.generate_batch([np.full(L, 3, np.int32) for L in lens],
+                         max_tokens=2)
+    with ServeFrontend(sched, overcommit=2.0,
+                       max_pending=4 * len(prompts)) as fe:
+        for qps in rates:
+            sched.stats.reset()
+            yield run_load(fe, prompts, max_tokens=max_tokens, qps=qps,
+                           eos_id=10 ** 6)
 
 
 def main() -> None:
@@ -71,6 +135,7 @@ def main() -> None:
                     help="KV page size (tokens) for --load-curve")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -79,34 +144,7 @@ def main() -> None:
 
     artifact = None
     if args.ckpt_dir:
-        mgr = CheckpointManager(args.ckpt_dir)
-        if CompressionArtifact.exists(args.ckpt_dir):
-            # Manifest-driven restore: the checkpoint's tree is compressed
-            # (and holds params only, as written by launch/compress.py), so
-            # the dense template must be rewritten before restore.
-            artifact = CompressionArtifact.load(args.ckpt_dir)
-            template = artifact.restore_template(values)
-            step, state = mgr.restore_latest({"params": template})
-            if state is not None:
-                values = state["params"]
-                t = artifact.manifest["totals"]
-                print(f"[restore] step {step} (compressed: "
-                      f"{len(artifact.manifest['tensors'])} tensors, "
-                      f"x{t['ratio']:.2f})")
-            else:
-                # manifest without a restorable step: serve the dense init
-                # rather than crashing manifest validation against it
-                print(f"[restore] {args.ckpt_dir}: manifest present but no "
-                      "checkpoint step; serving dense init")
-                artifact = None
-        else:
-            step, state = mgr.restore_latest(
-                {"step": jnp.zeros((), jnp.int32), "params": values,
-                 "opt": None}
-            )
-            if state is not None:
-                values = state["params"]
-                print(f"[restore] step {step}")
+        values, artifact = restore_checkpoint(args.ckpt_dir, values)
 
     if args.compress and artifact is None:
         policy = CompressionPolicy(
@@ -145,38 +183,23 @@ def main() -> None:
               f"{eng.compression}")
 
     if args.load_curve:
-        import numpy as np
-
-        from repro.serving import Scheduler, ServeFrontend, run_load
-
-        max_len = args.prompt_len + args.steps
-        page = min(args.page_size, max_len)
-        while max_len % page != 0:
-            page //= 2
-        sched = Scheduler(eng, num_slots=args.num_slots, page_size=page,
-                          max_len=max_len)
         rng = np.random.default_rng(args.seed)
         lens = sorted({max(2, args.prompt_len // 2), args.prompt_len})
-        # warm-up traces every prefill bucket + the decode step
-        sched.generate_batch([np.full(L, 3, np.int32) for L in lens],
-                             max_tokens=2)
         prompts = [
             rng.integers(0, cfg.vocab_size, size=int(rng.choice(lens)))
             .astype(np.int32)
             for _ in range(args.requests)
         ]
         print("qps,completed,goodput_toks_per_s,p50_ms,p99_ms,peak,evictions")
-        with ServeFrontend(sched, overcommit=2.0,
-                           max_pending=4 * args.requests) as fe:
-            for qps in args.qps:
-                sched.stats.reset()
-                res = run_load(fe, prompts, max_tokens=args.steps, qps=qps,
-                               eos_id=10 ** 6)
-                print(f"{qps:g},{res.completed},"
-                      f"{res.goodput_toks_per_s:.1f},"
-                      f"{1e3 * res.p50_latency_s:.1f},"
-                      f"{1e3 * res.p99_latency_s:.1f},"
-                      f"{res.peak_running},{res.evictions}")
+        for res in serve_load_curve(
+            eng, prompts, max_tokens=args.steps, rates=args.qps,
+            num_slots=args.num_slots, page_size=args.page_size,
+        ):
+            print(f"{res.qps:g},{res.completed},"
+                  f"{res.goodput_toks_per_s:.1f},"
+                  f"{1e3 * res.p50_latency_s:.1f},"
+                  f"{1e3 * res.p99_latency_s:.1f},"
+                  f"{res.peak_running},{res.evictions}")
         return
 
     prompts = jax.random.randint(

@@ -18,13 +18,14 @@ import time
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import SHAPES, get_config, reduced_for_smoke
 from repro.configs.base import ParallelConfig, ShapeConfig
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import make_pipeline
 from repro.distributed.fault_tolerance import Heartbeat, StepTimer, run_with_restarts
 from repro.distributed.sharding import activation_rules
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.optim import warmup_cosine
 from repro.training import init_train_state, make_train_step, state_shardings
 
@@ -34,17 +35,6 @@ def parse_mesh(s: str):
     axes = ("pod", "data", "model")[-len(dims):] if len(dims) <= 3 else None
     assert axes, f"mesh must have <= 3 dims, got {s}"
     return dims, axes
-
-
-def _disable_persistent_compilation_cache() -> None:
-    """jax 0.4.x: a compilation-cache hit on the post-restart re-jit (same
-    process, donated buffers) corrupts the step — NaN loss, then SIGSEGV.
-    The supervised launcher restarts in-process, so it must never use the
-    persistent cache on this jax."""
-    if jax.config.jax_compilation_cache_dir:
-        print("[supervisor] persistent compilation cache disabled "
-              "(unsafe across in-process restarts on jax 0.4.x)")
-        jax.config.update("jax_compilation_cache_dir", None)
 
 
 def train_once(args, attempt: int) -> None:
@@ -77,7 +67,7 @@ def train_once(args, attempt: int) -> None:
     step_fn = make_train_step(cfg, pcfg, warmup_cosine(args.lr, args.warmup, args.steps))
     pipe = make_pipeline(cfg, shape, mesh, seed=args.seed)
 
-    with set_mesh(mesh), activation_rules(pcfg, mesh):
+    with jax.set_mesh(mesh), activation_rules(pcfg, mesh):
         jstep = jax.jit(
             step_fn, in_shardings=(sh, None), out_shardings=(sh, None),
             donate_argnums=0,
@@ -126,7 +116,7 @@ def main() -> None:
                     help="inject one crash at this step (tests restart path)")
     args = ap.parse_args()
 
-    _disable_persistent_compilation_cache()
+    enable_compile_cache()
     restarts = run_with_restarts(
         lambda attempt: train_once(args, attempt),
         max_restarts=args.max_restarts,

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, ParallelConfig
 from repro.distributed import sharding as shd
 from repro.models import init_model, train_loss
@@ -121,7 +120,7 @@ def init_train_state(key, cfg: ModelConfig, pcfg: ParallelConfig, mesh: Mesh) ->
             opt=opt.init(values),
         )
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(init, out_shardings=sh)()
 
 

@@ -405,10 +405,29 @@ def test_auto_pool_chunk_memory_model():
     assert auto_pool_chunk(512, 32, 8, 64, budget_bytes=1) == 64
 
 
+def test_auto_decompose_chunk_memory_model():
+    """Greedy/alternating pools chunk by their working set against a
+    device-memory budget: whole when it fits, evenly split when not — a
+    whole-model pool at tile 32x128 does not fit one 16 GB chip."""
+    from repro.compression.execute import (
+        auto_decompose_chunk, decompose_tile_bytes,
+    )
+
+    per = decompose_tile_bytes(32, 128)
+    assert per == 4 * 4 * 32 * 128
+    assert auto_decompose_chunk(1000, 32, 128) == 1000
+    total = 313_344                          # granite-moe-1b-a400m, 32x128
+    chunk = auto_decompose_chunk(total, 32, 128)
+    n_chunks = -(-total // chunk)
+    assert chunk * per <= 1 << 30 and chunk * n_chunks >= total
+    assert chunk * (n_chunks - 1) < total    # an even split, no empty chunk
+    assert auto_decompose_chunk(10, 32, 128, budget_bytes=1) == 1
+
+
 def test_auto_chunk_recorded_in_pool_stats(monkeypatch):
     """execute_plan(max_pool_tiles="auto") chunks BBO pools by the memory
     model (env-overridable budget) and records the policy + model input in
-    the pool stats; non-BBO pools stay unchunked."""
+    the pool stats; a small non-BBO pool fits its device budget whole."""
     from repro.compression.execute import POOL_BUDGET_ENV, surrogate_tile_bytes
 
     values = {"a": {"w": jax.random.normal(jax.random.PRNGKey(3), (24, 32))}}

@@ -163,7 +163,7 @@ def test_export_load_roundtrip():
 def test_tune_returns_valid_best_and_trials():
     x, mp, C = _operands(jax.random.PRNGKey(0), 2, 2, 16, 4, 32, T=4)
     best, trials = autotune.tune(x, mp, C, repeats=1, iters=2)
-    assert best.mode in ("jnp", "grid", "decode", "stream")
+    assert best.mode in ("jnp", "grid", "decode")
     timed = [t for t in trials if "seconds" in t]
     assert len(timed) >= 2
     assert best.to_dict() in [t["schedule"] for t in timed]
@@ -176,12 +176,17 @@ def test_tune_grouped_routes_by_ndim():
     x, mp, C = _operands(jax.random.PRNGKey(1), 1, 2, 8, 3, 16, T=2, E=3)
     best, trials = autotune.tune(
         x, mp, C, repeats=1, iters=2,
-        schedules=[Schedule("jnp", "dot"), Schedule("stream", "unpack"),
+        schedules=[Schedule("jnp", "dot"), Schedule("grid", "unpack"),
                    Schedule("jnp", "bitplane")],
     )
-    # "stream" is 2D-only: the grouped search must skip it, not time it
-    assert best.mode == "jnp"
-    assert all(t["schedule"]["mode"] != "stream" for t in trials)
+    # 3D operands time the grouped kernel: every schedule ran, none failed
+    assert best.mode in ("jnp", "grid")
+    assert [t["schedule"]["mode"] for t in trials] == ["jnp", "grid", "jnp"]
+    assert all("seconds" in t for t in trials)
+    # a mode no kernel has is refused up front, not skipped in silence
+    with pytest.raises(ValueError, match="unknown bitlinear mode"):
+        autotune.tune(x, mp, C, repeats=1, iters=1,
+                      schedules=[Schedule("stream", "unpack")])
 
 
 # ---------------------------------------------------------------------------

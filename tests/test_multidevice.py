@@ -16,7 +16,6 @@ def run_py(code: str, devices: int = 8, timeout: int = 560) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,
@@ -29,7 +28,7 @@ def run_py(code: str, devices: int = 8, timeout: int = 560) -> str:
 def test_sharded_training_loss_decreases_and_elastic_restore(tmp_path):
     out = run_py(f"""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_for_smoke
         from repro.configs.base import ParallelConfig, ShapeConfig
         from repro.training import init_train_state, make_train_step, state_shardings
@@ -46,7 +45,7 @@ def test_sharded_training_loss_decreases_and_elastic_restore(tmp_path):
         sh = state_shardings(cfg, pcfg, mesh)
         step_fn = make_train_step(cfg, pcfg, warmup_cosine(1e-3, 10, 100))
         pipe = make_pipeline(cfg, shape, mesh)
-        with set_mesh(mesh), activation_rules(pcfg, mesh):
+        with jax.set_mesh(mesh), activation_rules(pcfg, mesh):
             jstep = jax.jit(step_fn, in_shardings=(sh, None), out_shardings=(sh, None), donate_argnums=0)
             losses = []
             for i in range(8):
@@ -73,7 +72,7 @@ def test_microbatch_accumulation_equivalence():
     """micro=2 and micro=1 produce (numerically close) identical updates."""
     out = run_py("""
         import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced_for_smoke
         from repro.configs.base import ParallelConfig, ShapeConfig
         from repro.training import init_train_state, make_train_step, state_shardings
@@ -91,7 +90,7 @@ def test_microbatch_accumulation_equivalence():
             sh = state_shardings(cfg, pcfg, mesh)
             fn = make_train_step(cfg, pcfg, constant(1e-3))
             pipe = make_pipeline(cfg, shape, mesh)
-            with set_mesh(mesh), activation_rules(pcfg, mesh):
+            with jax.set_mesh(mesh), activation_rules(pcfg, mesh):
                 jstep = jax.jit(fn, in_shardings=(sh, None), out_shardings=(sh, None))
                 state, m = jstep(state, pipe.batch_at(0))
             outs[micro] = (float(m["loss"]), state.params)
@@ -111,10 +110,8 @@ def test_injected_failure_restart_cli(tmp_path):
     resumes from the checkpoint."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    # Deliberately points at a persistent compilation cache: on jax 0.4.x a
-    # cache hit on the post-restart re-jit (same process, donated buffers)
-    # corrupts the step — NaN loss then SIGSEGV — so the launcher must
-    # disable it itself (_disable_persistent_compilation_cache).
+    # A persistent compilation cache of its own: the in-process re-jit
+    # after the restart (donated buffers) runs against a warm cache.
     env.setdefault("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jax_cache"))
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.train",
