@@ -23,6 +23,7 @@ see docs/compression_api.md.
 
 from __future__ import annotations
 
+import functools
 import math
 import os
 
@@ -256,6 +257,69 @@ def _pack_tensor(t: TensorPlan, M_seg, C_seg, dtype):
     return {"m_packed": packed, "C": C_out}
 
 
+def _pack_leaf(t: TensorPlan, leaf, M_seg, C_seg, err_seg):
+    """One planned tensor's pooled rows -> (compressed leaf, its bytes,
+    mean rel_err, manifest entry).  The two host reads of the device
+    (``rel_err`` and ``tile_resid``) wait for the tensor's pool."""
+    err = float(jnp.mean(err_seg))
+    # per-tile residual against the STORED representation (cast C /
+    # int8 q·scale) — the baseline the delta drift metric compares
+    # against
+    if t.method == "int8":
+        w = _pack_tensor_int8(t, M_seg, C_seg)
+        nb = quantized.intquant_num_bytes(w)
+        resid = _int8_tile_residuals(_tensor_tiles(leaf, t), M_seg, C_seg)
+        leaf_spec = {
+            "q": {
+                "shape": list(w["q"].shape),
+                "dtype": str(w["q"].dtype),
+            },
+            "scale": {
+                "shape": list(w["scale"].shape),
+                "dtype": str(w["scale"].dtype),
+            },
+        }
+    else:
+        w = _pack_tensor(t, M_seg, C_seg, leaf.dtype)
+        nb = quantized.compressed_num_bytes(w)
+        resid = tile_residuals(
+            _tensor_tiles(leaf, t), M_seg,
+            w["C"].reshape(-1, t.K, t.tile_d),
+        )
+        leaf_spec = {
+            "m_packed": {
+                "shape": list(w["m_packed"].shape),
+                "dtype": str(w["m_packed"].dtype),
+            },
+            "C": {"shape": list(w["C"].shape), "dtype": str(w["C"].dtype)},
+        }
+    entry = {
+        "shape": list(t.shape),
+        "dtype": t.dtype,
+        "groups": t.groups,
+        "group_dims": list(t.shape[:-2]),
+        "tile_n": t.tile_n,
+        "tile_d": t.tile_d,
+        "K": t.K,
+        "method": t.method,
+        "rule": t.rule,
+        "leaf_index": t.leaf_index,
+        "bbo_iters": t.bbo_iters,
+        "num_tiles": t.num_tiles,
+        "orig_bytes": t.orig_bytes,
+        "new_bytes": int(nb),
+        "rel_err": err,
+        "tile_resid": [float(f"{v:.8g}") for v in np.asarray(resid)],
+        **leaf_spec,
+    }
+    return w, nb, err, entry
+
+
+# Host spans (``jax.profiler.TraceAnnotation``, ``repro.execute*``) mark
+# each host stage of a job in a profiler trace, beside the device scopes of
+# ``compress_tile_batch`` (docs/compression_api.md, "Tracing a job").  With
+# no profiler running a span costs about a microsecond.
+@functools.partial(jax.profiler.annotate_function, name="repro.execute")
 def execute_plan(
     plan: CompressionPlan,
     values,
@@ -309,7 +373,12 @@ def execute_plan(
         n_chunks = -(-total // chunk)
         bbo_key = jax.random.fold_in(jax.random.fold_in(key, 0x706F6F6C), pidx)
         parts, chunk_sizes = [], []
-        for ci, (ct, ck) in enumerate(_iter_chunks(members, leaves, key, chunk)):
+        chunks = _iter_chunks(members, leaves, key, chunk)
+        for ci in range(n_chunks):
+            with jax.profiler.TraceAnnotation(
+                "repro.execute.assemble", pool=pidx, chunk=ci
+            ):
+                ct, ck = next(chunks)
             if mesh is not None:
                 ct, ck, sharded = _shard_pool(ct, ck, mesh)
                 if not sharded:
@@ -320,15 +389,19 @@ def execute_plan(
                         "running replicated"
                     )
             chunk_sizes.append(int(ct.shape[0]))
-            if method == "int8":
-                # closed-form baseline: no solver, keys unused (the rounding
-                # is deterministic regardless of chunking)
-                parts.append(quantize_tile_batch(ct))
-            else:
-                parts.append(compress_tile_batch(
-                    ct, ck, jax.random.fold_in(bbo_key, ci), K, method,
-                    bbo_iters=max(bbo_iters, 1), backend=backend,
-                ))
+            with jax.profiler.TraceAnnotation(
+                "repro.execute.dispatch", pool=pidx, chunk=ci,
+                tiles=chunk_sizes[-1],
+            ):
+                if method == "int8":
+                    # closed-form baseline: no solver, keys unused (the
+                    # rounding is deterministic regardless of chunking)
+                    parts.append(quantize_tile_batch(ct))
+                else:
+                    parts.append(compress_tile_batch(
+                        ct, ck, jax.random.fold_in(bbo_key, ci), K, method,
+                        bbo_iters=max(bbo_iters, 1), backend=backend,
+                    ))
         if len(parts) == 1:
             M, C, errs = parts[0]
         else:
@@ -378,59 +451,13 @@ def execute_plan(
         if t is None:
             out.append(leaf)
             continue
-        M_seg, C_seg, err_seg = results[path]
-        err = float(jnp.mean(err_seg))
-        # per-tile residual against the STORED representation (cast C /
-        # int8 q·scale) — the baseline the delta drift metric compares
-        # against
-        if t.method == "int8":
-            w = _pack_tensor_int8(t, M_seg, C_seg)
-            nb = quantized.intquant_num_bytes(w)
-            resid = _int8_tile_residuals(_tensor_tiles(leaf, t), M_seg, C_seg)
-            leaf_spec = {
-                "q": {
-                    "shape": list(w["q"].shape),
-                    "dtype": str(w["q"].dtype),
-                },
-                "scale": {
-                    "shape": list(w["scale"].shape),
-                    "dtype": str(w["scale"].dtype),
-                },
-            }
-        else:
-            w = _pack_tensor(t, M_seg, C_seg, leaf.dtype)
-            nb = quantized.compressed_num_bytes(w)
-            resid = tile_residuals(
-                _tensor_tiles(leaf, t), M_seg,
-                w["C"].reshape(-1, t.K, t.tile_d),
+        with jax.profiler.TraceAnnotation(
+            "repro.execute.pack", tensor=path, tiles=t.num_tiles
+        ):
+            w, nb, err, manifest_tensors[path] = _pack_leaf(
+                t, leaf, *results[path]
             )
-            leaf_spec = {
-                "m_packed": {
-                    "shape": list(w["m_packed"].shape),
-                    "dtype": str(w["m_packed"].dtype),
-                },
-                "C": {"shape": list(w["C"].shape), "dtype": str(w["C"].dtype)},
-            }
         compressed.append((path, t.orig_bytes, nb, err))
-        manifest_tensors[path] = {
-            "shape": list(t.shape),
-            "dtype": t.dtype,
-            "groups": t.groups,
-            "group_dims": list(t.shape[:-2]),
-            "tile_n": t.tile_n,
-            "tile_d": t.tile_d,
-            "K": t.K,
-            "method": t.method,
-            "rule": t.rule,
-            "leaf_index": t.leaf_index,
-            "bbo_iters": t.bbo_iters,
-            "num_tiles": t.num_tiles,
-            "orig_bytes": t.orig_bytes,
-            "new_bytes": int(nb),
-            "rel_err": err,
-            "tile_resid": [float(f"{v:.8g}") for v in np.asarray(resid)],
-            **leaf_spec,
-        }
         out.append(w)
         if verbose:
             print(
@@ -438,22 +465,23 @@ def execute_plan(
                 f"rel_err {err:.3f}"
             )
 
-    ob = sum(c[1] for c in compressed)
-    nb_total = sum(c[2] for c in compressed)
-    manifest = {
-        "format": MANIFEST_FORMAT,
-        "policy": plan.policy.to_dict(),
-        "solver_backend": backend,
-        "tensors": manifest_tensors,
-        "skipped": {p: r for p, r in report_skipped},
-        "pools": pool_stats,
-        "totals": {
-            "orig_bytes": int(ob),
-            "new_bytes": int(nb_total),
-            "ratio": ob / max(nb_total, 1),
-        },
-    }
-    if plan.autotune is not None:
-        manifest["autotune"] = plan.autotune
-    artifact = CompressionArtifact(manifest)
+    with jax.profiler.TraceAnnotation("repro.execute.manifest"):
+        ob = sum(c[1] for c in compressed)
+        nb_total = sum(c[2] for c in compressed)
+        manifest = {
+            "format": MANIFEST_FORMAT,
+            "policy": plan.policy.to_dict(),
+            "solver_backend": backend,
+            "tensors": manifest_tensors,
+            "skipped": {p: r for p, r in report_skipped},
+            "pools": pool_stats,
+            "totals": {
+                "orig_bytes": int(ob),
+                "new_bytes": int(nb_total),
+                "ratio": ob / max(nb_total, 1),
+            },
+        }
+        if plan.autotune is not None:
+            manifest["autotune"] = plan.autotune
+        artifact = CompressionArtifact(manifest)
     return jax.tree_util.tree_unflatten(treedef, out), artifact

@@ -164,17 +164,20 @@ def _propose(key, state: _State, cfg: BBOConfig):
     """Surrogate fit + Thompson sample + Ising solve -> candidate x."""
     k_fit, k_solve = jax.random.split(key)
     if cfg.algo == "rs":
-        x = jax.random.rademacher(k_solve, (cfg.n,), dtype=cfg.dtype)
+        with jax.named_scope("bbo.ising"):
+            x = jax.random.rademacher(k_solve, (cfg.n,), dtype=cfg.dtype)
         return x, state
-    (h, B), state = _sample_ising(k_fit, state, cfg)
-    x, _ = ising.solve_many(
-        cfg.solver,
-        k_solve,
-        ising.IsingProblem(h[None], B[None]),
-        num_sweeps=cfg.num_sweeps,
-        num_reads=cfg.num_reads,
-        backend=cfg.backend,
-    )
+    with jax.named_scope("bbo.surrogate"):
+        (h, B), state = _sample_ising(k_fit, state, cfg)
+    with jax.named_scope("bbo.ising"):
+        x, _ = ising.solve_many(
+            cfg.solver,
+            k_solve,
+            ising.IsingProblem(h[None], B[None]),
+            num_sweeps=cfg.num_sweeps,
+            num_reads=cfg.num_reads,
+            backend=cfg.backend,
+        )
     return x[0].astype(cfg.dtype), state
 
 
@@ -190,31 +193,33 @@ def run_bbo(key: jax.Array, cfg: BBOConfig, f: Callable) -> BBOResult:
     mp = cfg.max_points
 
     k_init, k_loop = jax.random.split(key)
-    X0 = jax.random.rademacher(k_init, (cfg.init_points, n), dtype=dtype)
-    y0 = jax.vmap(f)(X0)
-
-    state = _State(
-        X=jnp.zeros((mp, n), dtype),
-        y=jnp.full((mp,), jnp.inf, dtype),
-        count=jnp.zeros((), jnp.int32),
-        stats=surrogate.init_stats(n, dtype),
-        hs=surrogate.init_horseshoe(n, dtype),
-        fm=surrogate.init_fm(jax.random.fold_in(k_init, 1), n, cfg.fm_rank, dtype),
-        best_x=X0[0],
-        best_y=jnp.asarray(jnp.inf, dtype),
-    )
 
     def put_init(state, row):
         return _append(state, row[0], row[1], dataclasses.replace(cfg, augment=False)), None
 
-    state, _ = jax.lax.scan(put_init, state, (X0, y0))
+    with jax.named_scope("bbo.init"):
+        X0 = jax.random.rademacher(k_init, (cfg.init_points, n), dtype=dtype)
+        y0 = jax.vmap(f)(X0)
+        state = _State(
+            X=jnp.zeros((mp, n), dtype),
+            y=jnp.full((mp,), jnp.inf, dtype),
+            count=jnp.zeros((), jnp.int32),
+            stats=surrogate.init_stats(n, dtype),
+            hs=surrogate.init_horseshoe(n, dtype),
+            fm=surrogate.init_fm(jax.random.fold_in(k_init, 1), n, cfg.fm_rank, dtype),
+            best_x=X0[0],
+            best_y=jnp.asarray(jnp.inf, dtype),
+        )
+        state, _ = jax.lax.scan(put_init, state, (X0, y0))
 
     def iteration(state: _State, key):
         k1, k2 = jax.random.split(key)
         x, state = _propose(k1, state, cfg)
-        x = _dedupe(k2, state, x)
-        yv = f(x)
-        state = _append(state, x, yv, cfg)
+        with jax.named_scope("bbo.evaluate"):
+            x = _dedupe(k2, state, x)
+            yv = f(x)
+        with jax.named_scope("bbo.append"):
+            state = _append(state, x, yv, cfg)
         return state, (state.best_y, x)
 
     state, (traj, proposed) = jax.lax.scan(
@@ -272,24 +277,9 @@ def run_bbo_many(
     mp = cfg.max_points + (1 if warm_x is not None else 0)
 
     k_init, k_fm, k_loop = jax.random.split(key, 3)
-    X0 = jax.random.rademacher(k_init, (P, cfg.init_points, n), dtype=dtype)
-    y0 = jax.vmap(f_batch, in_axes=1, out_axes=1)(X0)          # (P, init_points)
 
     def bcast(tree):
         return jax.tree.map(lambda a: jnp.broadcast_to(a, (P,) + a.shape), tree)
-
-    state = _State(
-        X=jnp.zeros((P, mp, n), dtype),
-        y=jnp.full((P, mp), jnp.inf, dtype),
-        count=jnp.zeros((P,), jnp.int32),
-        stats=bcast(surrogate.init_stats(n, dtype)),
-        hs=bcast(surrogate.init_horseshoe(n, dtype)),
-        fm=jax.vmap(lambda k: surrogate.init_fm(k, n, cfg.fm_rank, dtype))(
-            jax.random.split(k_fm, P)
-        ),
-        best_x=X0[:, 0],
-        best_y=jnp.full((P,), jnp.inf, dtype),
-    )
 
     append_plain = jax.vmap(
         functools.partial(_append, cfg=dataclasses.replace(cfg, augment=False))
@@ -301,33 +291,54 @@ def run_bbo_many(
     def put_init(state, row):
         return append_plain(state, row[0], row[1]), None
 
-    state, _ = jax.lax.scan(
-        put_init, state, (jnp.swapaxes(X0, 0, 1), jnp.swapaxes(y0, 0, 1))
-    )
-
-    if warm_x is not None:
-        xw = warm_x.astype(dtype)
-        state = append_plain(state, xw, f_batch(xw))
+    # Named scopes label the ops of each stage for a profiler trace
+    # (docs/compression_api.md, "Tracing a job"); they change no op.
+    with jax.named_scope("bbo.init"):
+        X0 = jax.random.rademacher(k_init, (P, cfg.init_points, n), dtype=dtype)
+        y0 = jax.vmap(f_batch, in_axes=1, out_axes=1)(X0)      # (P, init_points)
+        state = _State(
+            X=jnp.zeros((P, mp, n), dtype),
+            y=jnp.full((P, mp), jnp.inf, dtype),
+            count=jnp.zeros((P,), jnp.int32),
+            stats=bcast(surrogate.init_stats(n, dtype)),
+            hs=bcast(surrogate.init_horseshoe(n, dtype)),
+            fm=jax.vmap(lambda k: surrogate.init_fm(k, n, cfg.fm_rank, dtype))(
+                jax.random.split(k_fm, P)
+            ),
+            best_x=X0[:, 0],
+            best_y=jnp.full((P,), jnp.inf, dtype),
+        )
+        state, _ = jax.lax.scan(
+            put_init, state, (jnp.swapaxes(X0, 0, 1), jnp.swapaxes(y0, 0, 1))
+        )
+        if warm_x is not None:
+            xw = warm_x.astype(dtype)
+            state = append_plain(state, xw, f_batch(xw))
 
     def iteration(state: _State, key):
         k_fit, k_solve, k_dupe = jax.random.split(key, 3)
         if cfg.algo == "rs":
-            x = jax.random.rademacher(k_solve, (P, n), dtype=dtype)
+            with jax.named_scope("bbo.ising"):
+                x = jax.random.rademacher(k_solve, (P, n), dtype=dtype)
         else:
-            (h, B), state = sample_many(jax.random.split(k_fit, P), state)
-            x, _ = ising.solve_many(
-                cfg.solver,
-                k_solve,
-                ising.IsingProblem(h, B),
-                num_sweeps=cfg.num_sweeps,
-                num_reads=cfg.num_reads,
-                backend=cfg.backend,
-                init_state=state.best_x if warm_x is not None else None,
-            )
-            x = x.astype(dtype)
-        x = dedupe_many(jax.random.split(k_dupe, P), state, x)
-        yv = f_batch(x)
-        state = append_cfg(state, x, yv)
+            with jax.named_scope("bbo.surrogate"):
+                (h, B), state = sample_many(jax.random.split(k_fit, P), state)
+            with jax.named_scope("bbo.ising"):
+                x, _ = ising.solve_many(
+                    cfg.solver,
+                    k_solve,
+                    ising.IsingProblem(h, B),
+                    num_sweeps=cfg.num_sweeps,
+                    num_reads=cfg.num_reads,
+                    backend=cfg.backend,
+                    init_state=state.best_x if warm_x is not None else None,
+                )
+                x = x.astype(dtype)
+        with jax.named_scope("bbo.evaluate"):
+            x = dedupe_many(jax.random.split(k_dupe, P), state, x)
+            yv = f_batch(x)
+        with jax.named_scope("bbo.append"):
+            state = append_cfg(state, x, yv)
         return state, (state.best_y, x)
 
     state, (traj, proposed) = jax.lax.scan(

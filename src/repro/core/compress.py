@@ -143,19 +143,21 @@ def compress_tile_batch(
             M, _, _ = dec.alternating_decompose(W_t, K, M0=M)
         return M
 
-    M = jax.vmap(init_one)(tiles, keys)
-
-    if M0 is not None:
-        M0 = jnp.where(M0.astype(jnp.float32) < 0.0, -1.0, 1.0)
-        if method in ("alternating", "bbo"):
-            M_warm = jax.vmap(
-                lambda W_t, m0: dec.alternating_decompose(W_t, K, M0=m0)[0]
-            )(tiles, M0)
-        else:
-            M_warm = M0
-        obj = jax.vmap(dec.objective)
-        better = obj(M_warm, tiles) < obj(M, tiles)
-        M = jnp.where(better[:, None, None], M_warm, M)
+    # Named scopes label each stage's ops in a profiler trace
+    # (docs/compression_api.md, "Tracing a job"); they change no op.
+    with jax.named_scope("compress.init"):
+        M = jax.vmap(init_one)(tiles, keys)
+        if M0 is not None:
+            M0 = jnp.where(M0.astype(jnp.float32) < 0.0, -1.0, 1.0)
+            if method in ("alternating", "bbo"):
+                M_warm = jax.vmap(
+                    lambda W_t, m0: dec.alternating_decompose(W_t, K, M0=m0)[0]
+                )(tiles, M0)
+            else:
+                M_warm = M0
+            obj = jax.vmap(dec.objective)
+            better = obj(M_warm, tiles) < obj(M, tiles)
+            M = jnp.where(better[:, None, None], M_warm, M)
 
     if method == "bbo":
         cfg = bbo_lib.BBOConfig(
@@ -170,21 +172,23 @@ def compress_tile_batch(
                 tiles, xs
             )
 
-        res = bbo_lib.run_bbo_many(
-            pool_key, cfg, f_batch, T,
-            warm_x=M.reshape(T, tn * K) if M0 is not None else None,
-        )
-        x_bbo = res.best_x.reshape(T, tn, K)
-        better = res.best_y < jax.vmap(lambda M_t, W_t: dec.objective(M_t, W_t))(
-            M, tiles
-        )
-        M = jnp.where(better[:, None, None], x_bbo, M)
+        with jax.named_scope("compress.bbo"):
+            res = bbo_lib.run_bbo_many(
+                pool_key, cfg, f_batch, T,
+                warm_x=M.reshape(T, tn * K) if M0 is not None else None,
+            )
+            x_bbo = res.best_x.reshape(T, tn, K)
+            better = res.best_y < jax.vmap(
+                lambda M_t, W_t: dec.objective(M_t, W_t)
+            )(M, tiles)
+            M = jnp.where(better[:, None, None], x_bbo, M)
 
-    C = jax.vmap(dec.least_squares_C)(M, tiles)
-    err = jax.vmap(
-        lambda M_t, W_t: jnp.sqrt(jnp.maximum(dec.objective(M_t, W_t), 0.0))
-        / jnp.maximum(jnp.linalg.norm(W_t), 1e-30)
-    )(M, tiles)
+    with jax.named_scope("compress.lstsq"):
+        C = jax.vmap(dec.least_squares_C)(M, tiles)
+        err = jax.vmap(
+            lambda M_t, W_t: jnp.sqrt(jnp.maximum(dec.objective(M_t, W_t), 0.0))
+            / jnp.maximum(jnp.linalg.norm(W_t), 1e-30)
+        )(M, tiles)
     return M, C, err
 
 

@@ -328,6 +328,7 @@ def _bitlinear_jit(x, m_packed, C, block_t, interpret, mode, math, r_chunk):
                 dimension_semantics=("parallel",),
             ),
             interpret=interpret,
+            name="bitlinear_decode",
         )(x, m_packed, C)
         return out[:T]
 
@@ -351,6 +352,7 @@ def _bitlinear_jit(x, m_packed, C, block_t, interpret, mode, math, r_chunk):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="bitlinear_grid",
     )(x, m_packed, C)
     return out[:T]
 
@@ -466,6 +468,7 @@ def _bitlinear_grouped_jit(x, m_packed, C, block_t, interpret, mode, math,
                 dimension_semantics=("parallel", "parallel"),
             ),
             interpret=interpret,
+            name="bitlinear_grouped_decode",
         )(x, m_packed, C)
         return out[:, :T]
 
@@ -493,6 +496,7 @@ def _bitlinear_grouped_jit(x, m_packed, C, block_t, interpret, mode, math,
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="bitlinear_grouped_grid",
     )(x, m_packed, C)
     return out[:, :T]
 

@@ -112,4 +112,5 @@ def flash_attention(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

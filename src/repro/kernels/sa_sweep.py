@@ -19,7 +19,8 @@ Two entry points:
     array whole, so any ``block_p`` meets Mosaic's (8, 128) block rule.
 ``sq_sweep_many``
     The constant-temperature simulated-quench path: same kernel, the
-    (P, S) schedule is just filled with one temperature.
+    (P, S) schedule is just filled with one temperature; a trace names
+    its kernel ``sq_sweep_many``, SA's ``sa_sweep_many``.
 ``sa_sweep``
     Backward-compatible single-problem wrapper.
 
@@ -137,18 +138,9 @@ def auto_block_p(P: int, per_problem_bytes: int, interpret: bool) -> int:
     return bp
 
 
-@functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
-def sa_sweep_many(
-    h: jax.Array,       # (P, n)
-    B: jax.Array,       # (P, n, n) symmetric, zero diag
-    x0: jax.Array,      # (P, chains, n) initial +-1 spins
-    rand: jax.Array,    # (P, chains, sweeps, n) uniforms in [0, 1)
-    temps: jax.Array,   # (P, sweeps) per-problem temperature schedules
-    block_p: int | None = None,
-    interpret: bool = False,
-):
-    """Batched SA: P problems x chains in one program.  Returns
-    (x (P, chains, n), energy (P, chains))."""
+def _sweep_many(h, B, x0, rand, temps, block_p, interpret, name):
+    """The batched kernel call behind ``sa_sweep_many`` and
+    ``sq_sweep_many``; ``name`` is the kernel's name in a profiler trace."""
     P, C, n = x0.shape
     S = temps.shape[1]
     per_problem = (
@@ -178,6 +170,7 @@ def sa_sweep_many(
             jax.ShapeDtypeStruct((P, C, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(
         h.astype(jnp.float32)[:, None, :],
         B.astype(jnp.float32),
@@ -187,6 +180,22 @@ def sa_sweep_many(
         temps.astype(jnp.float32)[:, None, :],
     )
     return x, e[..., 0]
+
+
+@functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
+def sa_sweep_many(
+    h: jax.Array,       # (P, n)
+    B: jax.Array,       # (P, n, n) symmetric, zero diag
+    x0: jax.Array,      # (P, chains, n) initial +-1 spins
+    rand: jax.Array,    # (P, chains, sweeps, n) uniforms in [0, 1)
+    temps: jax.Array,   # (P, sweeps) per-problem temperature schedules
+    block_p: int | None = None,
+    interpret: bool = False,
+):
+    """Batched SA: P problems x chains in one program.  Returns
+    (x (P, chains, n), energy (P, chains))."""
+    return _sweep_many(h, B, x0, rand, temps, block_p, interpret,
+                       "sa_sweep_many")
 
 
 @functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
@@ -202,7 +211,8 @@ def sq_sweep_many(
     """Simulated quench: constant-temperature path through the SA kernel."""
     P, _, S, _ = rand.shape
     temps = jnp.full((P, S), temperature, jnp.float32)
-    return sa_sweep_many(h, B, x0, rand, temps, block_p=block_p, interpret=interpret)
+    return _sweep_many(h, B, x0, rand, temps, block_p, interpret,
+                       "sq_sweep_many")
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -131,6 +131,7 @@ def sqa_sweep_many(
             jax.ShapeDtypeStruct((P, T, C, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="sqa_sweep_many",
     )(
         h.astype(jnp.float32)[:, None, :],
         B.astype(jnp.float32),
