@@ -72,9 +72,12 @@ def surrogate_tile_bytes(tile_n: int, K: int, bbo_iters: int) -> int:
     """Per-tile BBO surrogate footprint in bytes — the memory model behind
     ``max_pool_tiles="auto"``.  One tile optimises n = tile_n*K spins with
     p = 1 + n + n(n-1)/2 quadratic features; the lock-step state carries the
-    (p, p) Gram matrix plus its Cholesky/solve temporaries (~3 p^2 floats)
-    and the acquired dataset ((init_points + iters) x (n + 2) floats,
-    init_points = n per core/compress.py)."""
+    (p, p) Gram matrix, the (p, p) posterior square root and the
+    matrix-vector temporaries (~3 p^2 floats) and the acquired dataset
+    ((init_points + iters) x (n + 2) floats, init_points = n per
+    core/compress.py).  The formula predates the square root, when its
+    3 p^2 covered the Gram matrix and its Cholesky temporaries; it is kept
+    unchanged on purpose, so the chunking stays as it was."""
     n = tile_n * K
     p = feat.num_features(n)
     max_points = n + max(bbo_iters, 1)

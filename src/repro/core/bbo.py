@@ -88,6 +88,20 @@ class BBOResult(NamedTuple):
     X: jax.Array             # (max_points, n) acquired dataset (padded)
     y: jax.Array             # (max_points,)
     count: jax.Array         # () number of valid rows in X / y
+    # () max |(G + I/prior_var) S S^T - I| of the final carried posterior
+    # square root (nBOCS/gBOCS); None for the algorithms that carry none
+    posterior_residual: jax.Array | None = None
+
+
+def _prior_var(cfg: BBOConfig) -> float | None:
+    """Coefficient variance of the conjugate prior (nBOCS: sigma2; gBOCS:
+    V0 = I), or None for the algorithms without one."""
+    return {"nbocs": cfg.sigma2, "gbocs": 1.0}.get(cfg.algo)
+
+
+def _residual(stats: surrogate.SuffStats, cfg: BBOConfig):
+    pv = _prior_var(cfg)
+    return None if pv is None else surrogate.posterior_residual(stats, pv)
 
 
 class _State(NamedTuple):
@@ -143,7 +157,7 @@ def _sample_ising(key, state: _State, cfg: BBOConfig):
     axis and hands the stacked (h, B) to one batched ``ising.solve_many``."""
     hs, fm = state.hs, state.fm
     if cfg.algo == "nbocs":
-        alpha = surrogate.sample_nbocs(key, state.stats, cfg.sigma2)
+        alpha = surrogate.sample_nbocs(key, state.stats)
         h, B = feat.coeffs_to_ising(alpha, cfg.n)
     elif cfg.algo == "gbocs":
         alpha = surrogate.sample_gbocs(key, state.stats, b0=cfg.beta)
@@ -204,7 +218,7 @@ def run_bbo(key: jax.Array, cfg: BBOConfig, f: Callable) -> BBOResult:
             X=jnp.zeros((mp, n), dtype),
             y=jnp.full((mp,), jnp.inf, dtype),
             count=jnp.zeros((), jnp.int32),
-            stats=surrogate.init_stats(n, dtype),
+            stats=surrogate.init_stats(n, dtype, _prior_var(cfg)),
             hs=surrogate.init_horseshoe(n, dtype),
             fm=surrogate.init_fm(jax.random.fold_in(k_init, 1), n, cfg.fm_rank, dtype),
             best_x=X0[0],
@@ -233,6 +247,7 @@ def run_bbo(key: jax.Array, cfg: BBOConfig, f: Callable) -> BBOResult:
         X=state.X,
         y=state.y,
         count=state.count,
+        posterior_residual=_residual(state.stats, cfg),
     )
 
 
@@ -300,7 +315,7 @@ def run_bbo_many(
             X=jnp.zeros((P, mp, n), dtype),
             y=jnp.full((P, mp), jnp.inf, dtype),
             count=jnp.zeros((P,), jnp.int32),
-            stats=bcast(surrogate.init_stats(n, dtype)),
+            stats=bcast(surrogate.init_stats(n, dtype, _prior_var(cfg))),
             hs=bcast(surrogate.init_horseshoe(n, dtype)),
             fm=jax.vmap(lambda k: surrogate.init_fm(k, n, cfg.fm_rank, dtype))(
                 jax.random.split(k_fm, P)
@@ -352,4 +367,5 @@ def run_bbo_many(
         X=state.X,
         y=state.y,
         count=state.count,
+        posterior_residual=jax.vmap(lambda st: _residual(st, cfg))(state.stats),
     )

@@ -4,13 +4,17 @@ Every surrogate consumes *sufficient statistics* of the acquired dataset and
 produces a Thompson sample of a quadratic pseudo-Boolean model, returned as
 Ising terms ``(h, B)`` via :func:`repro.core.features.coeffs_to_ising`.
 
-Beyond-paper optimisation (recorded in EXPERIMENTS.md): the paper refits the
-Bayesian regression from scratch each iteration (their complexity analysis:
-O(n^2) iterations x O(p^3) solve).  We maintain the Gram matrix
-``G = Phi^T Phi``, the moment vector ``F = Phi^T y`` and scalar moments
-incrementally (rank-1 update per acquired point), so an iteration costs one
-p x p Cholesky instead of a (points x p) regression rebuild.  This is exact,
-not an approximation.
+Beyond-paper optimisation: the paper refits the Bayesian regression from
+scratch each iteration (their complexity analysis: O(n^2) iterations x
+O(p^3) solve).  We maintain the Gram matrix ``G = Phi^T Phi``, the moment
+vector ``F = Phi^T y`` and scalar moments incrementally (rank-1 update per
+acquired point).  For the two conjugate priors (nBOCS, gBOCS) the posterior
+precision ``A = G + I/prior_var`` does not depend on ``y``, so we also carry
+a square root ``S`` of its inverse (``S S^T = A^{-1}``) and update it per
+point by Potter's rank-1 form of Sherman-Morrison, O(p^2).  A Thompson draw
+is then two matrix-vector products, with no factorisation or triangular
+solve.  This is exact algebra, not an approximation.  vBOCS keeps a p x p
+Cholesky per Gibbs step: its prior precision changes every step.
 
 Surrogates:
   * ``nbocs``  — normal prior  alpha_k ~ N(0, sigma2)           (conjugate)
@@ -33,6 +37,7 @@ __all__ = [
     "SuffStats",
     "init_stats",
     "update_stats",
+    "posterior_residual",
     "sample_nbocs",
     "sample_gbocs",
     "HorseshoeState",
@@ -55,17 +60,50 @@ class SuffStats(NamedTuple):
     Sy: jax.Array      # ()      sum y
     Syy: jax.Array     # ()      sum y^2
     count: jax.Array   # ()      number of points (float for jit arithmetic)
+    # (p, p) square root of the conjugate posterior covariance,
+    # S S^T = (G + I/prior_var)^{-1}; None when no prior variance was given
+    S: jax.Array | None = None
 
 
-def init_stats(n: int, dtype=jnp.float32) -> SuffStats:
+def init_stats(n: int, dtype=jnp.float32, prior_var: float | None = None) -> SuffStats:
+    """Empty statistics for n spins.  ``prior_var`` (the conjugate prior's
+    coefficient variance) starts the carried posterior square root at
+    ``sqrt(prior_var) I``; without it ``S`` stays None and nothing more is
+    carried."""
     p = feat.num_features(n)
+    S = None
+    if prior_var is not None:
+        S = jnp.sqrt(jnp.asarray(prior_var, dtype)) * jnp.eye(p, dtype=dtype)
     return SuffStats(
         G=jnp.zeros((p, p), dtype),
         F=jnp.zeros((p,), dtype),
         Sy=jnp.zeros((), dtype),
         Syy=jnp.zeros((), dtype),
         count=jnp.zeros((), dtype),
+        S=S,
     )
+
+
+# Matrix-vector products with S as multiply-and-sum: exact float32 on every
+# backend.  A TPU dot at default precision rounds its operands to bfloat16,
+# which would compound over the rank-1 updates into a wrong posterior.
+def _mv(S, v):
+    """S @ v."""
+    return jnp.sum(S * v[None, :], axis=1)
+
+
+def _mtv(S, v):
+    """S^T @ v."""
+    return jnp.sum(S * v[:, None], axis=0)
+
+
+def _potter_update(S: jax.Array, phi: jax.Array) -> jax.Array:
+    """The square root after one more point: S' S'^T = ((S S^T)^{-1} +
+    phi phi^T)^{-1} for S' = S - (S v) v^T / (r (r + 1)), with v = S^T phi
+    and r = sqrt(1 + v.v)."""
+    v = _mtv(S, phi)
+    r = jnp.sqrt(1.0 + jnp.sum(v * v))
+    return S - jnp.outer(_mv(S, v) / (r * (r + 1.0)), v)
 
 
 def update_stats(stats: SuffStats, x: jax.Array, y: jax.Array) -> SuffStats:
@@ -76,7 +114,18 @@ def update_stats(stats: SuffStats, x: jax.Array, y: jax.Array) -> SuffStats:
         Sy=stats.Sy + y,
         Syy=stats.Syy + y * y,
         count=stats.count + 1.0,
+        S=None if stats.S is None else _potter_update(stats.S, phi),
     )
+
+
+def posterior_residual(stats: SuffStats, prior_var: float) -> jax.Array:
+    """max |(G + I/prior_var) S S^T - I|: how far the carried square root
+    has drifted from the posterior it stands for (0 in exact arithmetic)."""
+    p = stats.G.shape[0]
+    eye = jnp.eye(p, dtype=stats.G.dtype)
+    hi = jax.lax.Precision.HIGHEST
+    AS = jnp.matmul(stats.G + eye / prior_var, stats.S, precision=hi)
+    return jnp.max(jnp.abs(jnp.matmul(AS, stats.S.T, precision=hi) - eye))
 
 
 def _standardised(stats: SuffStats):
@@ -104,19 +153,28 @@ def _chol_gaussian_sample(key, mean, precision_chol):
     )
 
 
+def _require_root(stats: SuffStats, prior: str) -> jax.Array:
+    if stats.S is None:
+        raise ValueError(
+            f"{prior} samples from the carried posterior square root: build "
+            f"the statistics with init_stats(..., prior_var=...)"
+        )
+    return stats.S
+
+
 # ---------------------------------------------------------------------------
 # nBOCS — normal prior (paper's best performer; sigma2 = 0.1 from Fig. 6)
 # ---------------------------------------------------------------------------
 
-def sample_nbocs(key: jax.Array, stats: SuffStats, sigma2: float = 0.1):
+def sample_nbocs(key: jax.Array, stats: SuffStats):
     """Thompson sample alpha ~ posterior under alpha_k ~ N(0, sigma2),
-    unit observation noise on standardised targets."""
+    unit observation noise on standardised targets.  ``stats`` must come
+    from ``init_stats(..., prior_var=sigma2)``: the draw
+    ``S (S^T F + z)`` is N(A^{-1} F, A^{-1}) with A = G + I/sigma2."""
+    S = _require_root(stats, "nBOCS")
     F_std, _ = _standardised(stats)
-    p = stats.G.shape[0]
-    A = stats.G + jnp.eye(p, dtype=stats.G.dtype) / sigma2
-    L = jnp.linalg.cholesky(A)
-    mu = jax.scipy.linalg.cho_solve((L, True), F_std)
-    return _chol_gaussian_sample(key, mu, L)
+    z = jax.random.normal(key, F_std.shape, F_std.dtype)
+    return _mv(S, _mtv(S, F_std) + z)
 
 
 # ---------------------------------------------------------------------------
@@ -126,20 +184,18 @@ def sample_nbocs(key: jax.Array, stats: SuffStats, sigma2: float = 0.1):
 def sample_gbocs(
     key: jax.Array, stats: SuffStats, a0: float = 1.0, b0: float = 0.001
 ):
+    """Thompson sample under the normal-gamma prior with V0 = I: ``stats``
+    must come from ``init_stats(..., prior_var=1.0)``, so S S^T = (G + I)^{-1}."""
+    S = _require_root(stats, "gBOCS")
     F_std, yty = _standardised(stats)
-    p = stats.G.shape[0]
-    A = stats.G + jnp.eye(p, dtype=stats.G.dtype)      # V0 = I
-    L = jnp.linalg.cholesky(A)
-    mu = jax.scipy.linalg.cho_solve((L, True), F_std)
+    mu = _mv(S, _mtv(S, F_std))
     a_n = a0 + stats.count / 2.0
     b_n = b0 + 0.5 * jnp.maximum(yty - mu @ F_std, 0.0)
     k1, k2 = jax.random.split(key)
     prec = jax.random.gamma(k1, a_n) / b_n             # sigma^{-2}
     sigma = jnp.sqrt(1.0 / jnp.maximum(prec, 1e-12))
-    z = jax.random.normal(k2, (p,), mu.dtype)
-    return mu + sigma * jax.scipy.linalg.solve_triangular(
-        L, z, trans="T", lower=True
-    )
+    z = jax.random.normal(k2, mu.shape, mu.dtype)
+    return mu + sigma * _mv(S, z)
 
 
 # ---------------------------------------------------------------------------

@@ -4,10 +4,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from scipy import linalg as scipy_linalg
 
 from repro.core import bbo as bbo_lib
 from repro.core import decomposition as dec
-from repro.core import features, ising, surrogate
+from repro.core import features, ising, surrogate, symmetry
 from repro.core.bruteforce import brute_force
 
 
@@ -91,17 +92,101 @@ def test_nbocs_recovers_known_quadratic():
     X = jax.random.rademacher(jax.random.PRNGKey(8), (npts, n), dtype=jnp.float32)
     Phi = jax.vmap(features.featurize)(X)
     y = Phi @ alpha_true
-    stats = surrogate.init_stats(n)
+    stats = surrogate.init_stats(n, prior_var=10.0)
     for i in range(npts):
         stats = surrogate.update_stats(stats, X[i], y[i])
     draws = jnp.stack([
-        surrogate.sample_nbocs(jax.random.PRNGKey(i), stats, sigma2=10.0)
+        surrogate.sample_nbocs(jax.random.PRNGKey(i), stats)
         for i in range(16)
     ])
     mean = jnp.mean(draws, axis=0)[1:]        # drop the constant feature
     at = alpha_true[1:]
     cos = float(mean @ at / (jnp.linalg.norm(mean) * jnp.linalg.norm(at)))
     assert cos > 0.98, cos
+
+
+def _posterior_rows(rows: str, N: int = 4, K: int = 2, m: int = 12):
+    """The rows a BBO run appends: m random init points, then the warm
+    start's row and / or one point's augmented orbit."""
+    n = N * K
+    X = jax.random.rademacher(jax.random.PRNGKey(20), (m, n), dtype=jnp.float32)
+    if "warm" in rows:
+        X = jnp.concatenate([X, -X[:1]])
+    if "orbit" in rows:
+        x = jax.random.rademacher(jax.random.PRNGKey(21), (n,), dtype=jnp.float32)
+        X = jnp.concatenate([X, symmetry.orbit_flat(x, N, K)])
+    y = jax.random.normal(jax.random.PRNGKey(22), (X.shape[0],))
+    return X, y
+
+
+@pytest.mark.parametrize("rows", ["init", "init+warm", "init+orbit",
+                                  "init+warm+orbit"])
+@pytest.mark.parametrize("prior_var", [0.1, 1.0], ids=["nbocs", "gbocs"])
+def test_square_root_posterior_matches_float64(prior_var, rows):
+    """After m rank-1 updates the carried S gives S S^T = (G + I/v)^{-1}
+    and S S^T F = cho_solve(A, F), both computed here in float64."""
+    X, y = _posterior_rows(rows)
+    stats = surrogate.init_stats(X.shape[1], prior_var=prior_var)
+    update = jax.jit(surrogate.update_stats)
+    for i in range(X.shape[0]):
+        stats = update(stats, X[i], y[i])
+    Phi = np.asarray(jax.vmap(features.featurize)(X), np.float64)
+    A = Phi.T @ Phi + np.eye(Phi.shape[1]) / prior_var
+    cov = np.linalg.inv(A)
+    S = np.asarray(stats.S, np.float64)
+    np.testing.assert_allclose(S @ S.T, cov, rtol=0, atol=1e-6 * np.abs(cov).max())
+    F_std = np.asarray(surrogate._standardised(stats)[0], np.float64)
+    mu_ref = scipy_linalg.cho_solve(scipy_linalg.cho_factor(A), F_std)
+    np.testing.assert_allclose(S @ (S.T @ F_std), mu_ref, rtol=0,
+                               atol=1e-5 * np.abs(mu_ref).max())
+
+
+def test_nbocs_draws_have_the_posterior_covariance():
+    """4,096 Thompson draws at fixed keys: their mean and covariance are the
+    posterior's, N(A^{-1} F, A^{-1}) with A = G + I/sigma2."""
+    X, y = _posterior_rows("init", N=2, K=2, m=10)
+    stats = surrogate.init_stats(X.shape[1], prior_var=0.1)
+    for i in range(X.shape[0]):
+        stats = surrogate.update_stats(stats, X[i], y[i])
+    keys = jax.random.split(jax.random.PRNGKey(23), 4096)
+    draws = np.asarray(jax.vmap(lambda k: surrogate.sample_nbocs(k, stats))(keys),
+                       np.float64)
+    Phi = np.asarray(jax.vmap(features.featurize)(X), np.float64)
+    cov = np.linalg.inv(Phi.T @ Phi + np.eye(Phi.shape[1]) / 0.1)
+    mu = cov @ np.asarray(surrogate._standardised(stats)[0], np.float64)
+    scale = np.sqrt(np.diag(cov))
+    assert np.abs(draws.mean(0) - mu).max() / scale.max() < 0.1
+    emp = np.cov(draws, rowvar=False)
+    assert np.abs(emp - cov).max() / np.abs(cov).max() < 0.1
+
+
+@pytest.mark.parametrize("algo,augment,warm", [
+    ("nbocs", False, False), ("nbocs", True, False), ("nbocs", False, True),
+    ("gbocs", False, False),
+])
+def test_run_bbo_many_posterior_residual(algo, augment, warm):
+    """A lock-step chunk's carried square roots still invert their final
+    posterior precision; algorithms without one report None."""
+    N, K, P = 4, 2, 6
+    W = jax.random.normal(jax.random.PRNGKey(24), (P, N, 16))
+    cfg = bbo_lib.BBOConfig(n=N * K, N=N, K=K, algo=algo, solver="sa",
+                            iters=12, init_points=8, augment=augment,
+                            backend="jnp")
+
+    def f_batch(xs):
+        return jax.vmap(lambda w, x: dec.objective_from_x(x, w, K))(W, xs)
+
+    warm_x = (jax.random.rademacher(jax.random.PRNGKey(25), (P, N * K),
+                                    dtype=jnp.float32) if warm else None)
+    res = bbo_lib.run_bbo_many(jax.random.PRNGKey(26), cfg, f_batch, P,
+                               warm_x=warm_x)
+    assert res.posterior_residual.shape == (P,)
+    assert float(jnp.max(res.posterior_residual)) <= 1e-5
+    rs = bbo_lib.run_bbo_many(jax.random.PRNGKey(26),
+                              bbo_lib.BBOConfig(n=N * K, N=N, K=K, algo="rs",
+                                                iters=2, init_points=8),
+                              f_batch, P)
+    assert rs.posterior_residual is None
 
 
 def test_fm_surrogate_learns():

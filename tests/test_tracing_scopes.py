@@ -13,6 +13,8 @@ import jax
 import pytest
 
 from repro import compression as comp
+from repro.core import bbo as bbo_lib
+from repro.core import decomposition as dec
 from repro.core.compress import compress_tile_batch
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -24,8 +26,8 @@ BBO_SCOPES = {"bbo.init", "bbo.surrogate", "bbo.ising", "bbo.evaluate",
               "bbo.append"}
 COMPRESS_SCOPES = {"compress.init", "compress.bbo", "compress.lstsq"}
 OP_NAME = re.compile(r'op_name="([^"]*)"')
-# the surrogate's factorisation and solves, as the CPU compiles them
-# (LAPACK calls) and as HLO names them elsewhere
+# a factorisation or triangular solve, as the CPU compiles it (LAPACK
+# calls) and as HLO names it elsewhere
 LINALG = re.compile(
     r"\b(cholesky|triangular-solve)\(|custom_call_target=\"[^\"]*"
     r"(potrf|trsm|Cholesky|TriangularSolve)")
@@ -61,8 +63,30 @@ def test_every_stage_of_a_bbo_chunk_has_its_scope(hlo):
     assert BBO_SCOPES | COMPRESS_SCOPES <= found
 
 
-def test_the_surrogate_linear_algebra_is_under_bbo_surrogate(hlo):
-    names = _op_names(hlo, LINALG)
+@pytest.fixture(scope="module")
+def vbocs_hlo():
+    """The compiled text of a vBOCS lock-step run: 4 problems, n = 8."""
+    cfg = bbo_lib.BBOConfig(n=8, N=4, K=2, algo="vbocs", iters=2,
+                            init_points=8, gibbs_steps=1, backend="jnp")
+    W = jax.random.normal(jax.random.PRNGKey(3), (4, 4, 16))
+
+    def f_batch(xs):
+        return jax.vmap(lambda w, x: dec.objective_from_x(x, w, 2))(W, xs)
+
+    fn = jax.jit(lambda key: bbo_lib.run_bbo_many(key, cfg, f_batch, 4))
+    return fn.lower(jax.random.PRNGKey(4)).compile().as_text()
+
+
+def test_an_nbocs_chunk_compiles_no_factorisation(hlo):
+    """nBOCS carries its posterior's square root, updated by rank 1 per
+    point: the compiled chunk holds no Cholesky or triangular solve."""
+    assert _op_names(hlo, LINALG) == []
+    assert not LINALG.search(hlo)
+
+
+def test_the_surrogate_linear_algebra_is_under_bbo_surrogate(vbocs_hlo):
+    """vBOCS still factors its posterior precision every Gibbs step."""
+    names = _op_names(vbocs_hlo, LINALG)
     assert names
     for name in names:
         assert "bbo.surrogate" in trace_scopes.scopes_of(name), name
