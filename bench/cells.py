@@ -3,12 +3,18 @@ traffic mix; their files, the cell's correctness limits and the per-layer
 metric readers are found by name under ``bench/``.
 
     bench/configs/<config>.json    model sizes as run, and the deployment
-    bench/traffic/<traffic>.json   the mix; its ``kind`` picks the runner
+    bench/traffic/<traffic>.json   the mix; its ``kind`` names the runner
+    bench/<kind>.py                ``Runner``, the class that drives a kind
     bench/limits/<cell>.json       the limit of each number ``correct`` compares
     bench/metrics/<metric>.py      ``read(ctx) -> float | None``
 
-A new cell, configuration, mix or metric is a new file and a new entry in
-``BENCHMARK.json``; nothing here names one.
+A configuration file maps each published key to a field of the program's
+``ModelConfig`` through ``HF_KEYS``, or through a ``keys`` map of its own,
+which overrides ``HF_KEYS`` for that file; a key the program has no field
+for is listed under ``not_run`` with the reason.
+
+A new cell, configuration, mix, kind or metric is a new file and a new
+entry in ``BENCHMARK.json``; nothing here names one.
 """
 
 from __future__ import annotations
@@ -38,6 +44,10 @@ HF_KEYS = {
     "torch_dtype": "dtype",
 }
 
+# Keys of a configuration file that describe it rather than size the model.
+META_KEYS = ("arch", "source", "model_type", "deployment", "assumed",
+             "reduced", "keys", "not_run")
+
 
 @dataclasses.dataclass
 class Cell:
@@ -48,6 +58,7 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    root: pathlib.Path = BENCH_DIR.parent
 
     @property
     def kind(self) -> str:
@@ -55,12 +66,10 @@ class Cell:
 
 
 def runner(cell: Cell):
-    """The class that drives a cell of the mix's ``kind``."""
-    if cell.kind == "compress":
-        from bench.compress import CompressCell
-
-        return CompressCell
-    raise ValueError(f"no runner for traffic kind {cell.kind!r}")
+    """``Runner`` of ``bench/<kind>.py`` in the cell's checkout: the class
+    that drives a cell of the mix's ``kind``."""
+    return _module(cell.root / "bench" / f"{cell.kind}.py",
+                   "bench_kind_").Runner
 
 
 def load_json(path: pathlib.Path) -> dict:
@@ -95,28 +104,42 @@ def load_cell(name: str, root: pathlib.Path | None = None) -> Cell:
            if m.get("workloads") is None or name in m["workloads"]]
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _reports(m, name, names)]
-    return Cell(name, w, config, traffic, limits, e2e, per_layer)
+    return Cell(name, w, config, traffic, limits, e2e, per_layer, root)
+
+
+def config_keys(config: dict) -> dict:
+    """Published key -> ModelConfig field for a configuration file: its own
+    ``keys`` over ``HF_KEYS``."""
+    return {**HF_KEYS, **config.get("keys", {})}
 
 
 def model_config(config: dict):
     """The program's ModelConfig for a configuration file: the registry's
-    entry for ``arch`` with every published size the file states."""
+    entry for ``arch`` with every mapped key the file states (lists as
+    tuples)."""
     from repro.configs import get_config
 
     base = get_config(config["arch"])
-    fields = {f: config[k] for k, f in HF_KEYS.items() if k in config}
+    fields = {f: tuple(config[k]) if isinstance(config[k], list) else config[k]
+              for k, f in config_keys(config).items() if k in config}
     return dataclasses.replace(base, **fields)
+
+
+def _module(path: pathlib.Path, prefix: str):
+    """The Python file ``path`` as a module (its name may hold dots and
+    dashes)."""
+    stem = pathlib.Path(path).stem
+    name = prefix + stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_module(root: pathlib.Path, name: str):
     """``bench/metrics/<name>.py`` as a module (names may hold dots)."""
-    path = pathlib.Path(root) / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _module(pathlib.Path(root) / "bench" / "metrics" / f"{name}.py",
+                   "bench_metric_")
 
 
 def metric_reader(root: pathlib.Path, name: str):

@@ -3,18 +3,19 @@ model, with the mix's policy.
 
 Set-up makes the dense model on the device from the seed, plans, and runs
 one whole job (every program compiled).  The window starts jobs until
-``seconds`` have passed; ``compress_MB_per_s`` is the dense bf16 bytes of
-the tensors the jobs compressed over the time from the window's start to
-the end of the last job started before the deadline.  Once the window is
-read, the float32 reference checks every job's answer tile by tile
-(``bench/reference.py``).
+``seconds`` have passed, ``AHEAD`` at a time; ``compress_MB_per_s`` is the
+dense bf16 bytes of the tensors the jobs compressed over the time from the
+window's start to the return of the last job started before the deadline.
+Once the window is read, the float32 reference checks every job's answer
+tile by tile (``bench/reference.py``).
 """
 
 from __future__ import annotations
 
-import contextlib
 import gc
 import time
+from collections import deque
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 
@@ -22,8 +23,13 @@ from bench import reference, weights
 from bench.cells import model_config
 from bench.device import log
 
+# Jobs in flight in the window: ~1.7 s of device work each, so the chip
+# holds some 5 s of work queued behind the job that the window waits for.
+AHEAD = 4
 
-def _policy(spec: dict):
+
+def policy(spec: dict):
+    """The program's CompressionPolicy for a mix's ``policy`` entry."""
     from repro.compression import CompressionPolicy
 
     pol = dict(spec)
@@ -43,7 +49,7 @@ class CompressCell:
         from repro.compression import plan_compression
 
         self.values = weights.dense(self.cfg, self.seed)
-        self.plan = plan_compression(self.values, _policy(self.traffic["policy"]))
+        self.plan = plan_compression(self.values, policy(self.traffic["policy"]))
         if not self.plan.tensors:
             raise ValueError("the mix's policy compresses no tensor")
         self._job(0)
@@ -68,30 +74,42 @@ class CompressCell:
         return answers, artifact
 
     def window(self, seconds: float, trace_dir: str | None):
-        """Jobs back to back until ``seconds`` have passed.  A traced run
-        profiles one job: a job's trace is ~120 MB, and reading a whole
-        window's back would not fit a run's time."""
-        jobs, t_last = [], None
-        profiler = (jax.profiler.trace(trace_dir) if trace_dir
-                    else contextlib.nullcontext())
-        with profiler:
+        """Jobs until ``seconds`` have passed, ``AHEAD`` in flight on as
+        many threads, so that the chip has work queued while the host
+        stands still.  Once the time is up no job starts; the window ends
+        when every job started has returned, and all of them count.  A
+        traced run profiles one job alone: a job's trace is ~120 MB, and
+        reading a whole window's back would not fit a run's time."""
+        jobs, ends = [], []
+        if trace_dir:
+            with jax.profiler.trace(trace_dir):
+                t0 = time.perf_counter()
+                with jax.profiler.TraceAnnotation("bench.window"):
+                    jobs.append(self._job(1))
+                    ends.append(time.perf_counter())
+            return {"t0": t0, "t_last": ends[-1], "jobs": jobs, "ends": ends}
+        with ThreadPoolExecutor(AHEAD) as pool:
             t0 = time.perf_counter()
-            with jax.profiler.TraceAnnotation("bench.window"):
-                j = 1
-                while time.perf_counter() < t0 + seconds:
-                    answers, artifact = self._job(j)
-                    t_last = time.perf_counter()
-                    jobs.append((answers, artifact))
+            pending, j = deque(), 1
+            while True:
+                if time.perf_counter() < t0 + seconds and len(pending) < AHEAD:
+                    pending.append(pool.submit(self._job, j))
                     j += 1
-                    if trace_dir:
-                        break
-        return {"t0": t0, "t_last": t_last, "jobs": jobs}
+                elif pending:
+                    jobs.append(pending.popleft().result())
+                    ends.append(time.perf_counter())
+                else:
+                    break
+        return {"t0": t0, "t_last": ends[-1], "jobs": jobs, "ends": ends}
 
     def end_to_end(self, w) -> dict:
         nbytes = sum(a.manifest["totals"]["orig_bytes"] for _, a in w["jobs"])
         span = w["t_last"] - w["t0"]
+        gaps = [b - a for a, b in zip([w["t0"]] + w["ends"], w["ends"])]
         log(f"[compress] {len(w['jobs'])} jobs, {nbytes} dense bytes in "
-            f"{span:.3f} s; per job {span / len(w['jobs']):.3f} s")
+            f"{span:.3f} s; per job {span / len(w['jobs']):.3f} s; seconds "
+            "from one job's return to the next: "
+            + " ".join(f"{g:.4f}" for g in gaps))
         return {"compress_MB_per_s": (nbytes / 1e6 / span, "MB/s")}
 
     def attempted(self, w) -> tuple[int, int]:
@@ -130,3 +148,6 @@ class CompressCell:
         for k, v in worst.items():
             log(f"[check] {k} {v}")
         return worst
+
+
+Runner = CompressCell

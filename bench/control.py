@@ -2,7 +2,7 @@
 """Readings that a cell's correctness limits are set from, on the chip.
 
     python3 bench/control.py --workload <cell> --seconds <s> --seeds 1 2 3 ...
-        [--fault keep_init|random_spins|half_tiles|altered_C]
+        [--fault <one of bench/faults.py FAULTS>]
 
 For each seed, in one process: a window of the cell's own load through the
 timed path (with ``--fault``, the program broken as ``bench/faults.py``
@@ -42,10 +42,13 @@ def main() -> int:
         faults.plant(args.fault)
     drv = cells.runner(cell)(cell, args.seeds[0])
     drv.setup(trace=False)
-    for seed in args.seeds:
-        drv.reseed(seed)
+    for i, seed in enumerate(args.seeds):
+        if i:
+            drv.reseed(seed)
         w = drv.window(args.seconds, None)
-        got = drv.check(drv.sample(w), control=True)
+        samples = drv.sample(w)
+        drv.free()
+        got = drv.check(samples, control=True)
         print(json.dumps({"seed": seed, "fault": args.fault,
                           "attempted": drv.attempted(w)[0], **got}),
               flush=True)

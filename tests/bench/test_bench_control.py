@@ -1,6 +1,7 @@
-"""The control and the planted faults that the cell's comparison must
-catch, at a size a test run can hold (CPU): the compress-bbo policy as the
-cell runs it, over 64 tiles, held to the cell's own committed limits.  The
+"""The control and the planted faults that each cell's comparison must
+catch, at a size a test run can hold (CPU), held to the cell's own
+committed limits: the compress-bbo policy as the cell runs it over 64
+tiles, and the serve-chat mix cut to 4 slots of a 2-layer granite.  The
 float8 reference in the program's place reads above them, and a run whose
 timed path is broken underneath comes out not correct."""
 
@@ -16,7 +17,8 @@ sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 
 from bench import cells, faults, reference, run  # noqa: E402
-from test_bench_cells import on_cpu, run_tiny, tiny_root  # noqa: E402,F401
+from test_bench_cells import (  # noqa: E402,F401
+    on_cpu, run_tiny, run_tiny_serve, tiny_root, tiny_serve_root)
 
 
 @pytest.fixture
@@ -67,6 +69,66 @@ def test_a_broken_timed_path_is_not_correct(fault, number, tmp_path,
     assert res["correct"] is False
     c = res["checks"][number]
     assert c["value"] > c["limit"], res["checks"]
+
+
+def test_serve_cell_correct_and_its_control_is_not(tmp_path, monkeypatch,
+                                                   on_cpu):  # noqa: F811
+    root = tiny_serve_root(tmp_path)
+    cell = cells.load_cell("tiny-moe.tiny-chat", root)
+    drv = cells.runner(cell)(cell, seed=5)
+    drv.setup(trace=False)
+    w = drv.window(1.0, None)
+    samples = drv.sample(w)
+    drv.free()
+    got = drv.check(samples, control=True)
+    compared = cell.traffic["compared"]
+    ok, _ = run._checks({k: got[k] for k in compared}, cell.limits)
+    assert ok, got
+    ok, checks = run._checks({k: got["control_" + k] for k in compared},
+                             cell.limits)
+    assert not ok, checks
+
+
+@pytest.mark.parametrize("fault,number", [("wrong_kv_page", "logit_err_p90"),
+                                          ("dropped_expert", "logit_err_p90"),
+                                          ("altered_token", "token_gap"),
+                                          ("altered_C", "c_gap")])
+def test_a_broken_serving_path_is_not_correct(fault, number, tmp_path,
+                                              monkeypatch, plant,
+                                              on_cpu):  # noqa: F811
+    """``wrong_kv_page``: decode reads the scratch page for each slot's
+    first; ``dropped_expert``: expert 0's output is dropped;
+    ``altered_token``: each first token is not the one the logits chose;
+    ``altered_C``: one tile's C of each served tensor is scaled where
+    ``execute_plan`` packs it, and the reference serves it as given, so
+    only the served tiles' check against the dense weights sees it
+    (``bench/faults.py``)."""
+    plant(fault)
+    res = run_tiny_serve(tiny_serve_root(tmp_path), monkeypatch)
+    assert res["correct"] is False
+    c = res["checks"][number]
+    assert c["value"] > c["limit"], res["checks"]
+
+
+def test_the_served_tiles_read_the_same_in_blocks(tmp_path, monkeypatch,
+                                                  on_cpu):  # noqa: F811
+    """The check of the served tiles against the dense weights takes them
+    in blocks (one batched SVD over an expert stack overflows the chip's
+    scoped memory); the blocks change no reading, the control's included."""
+    from bench import weights
+
+    root = tiny_serve_root(tmp_path)
+    cell = cells.load_cell("tiny-moe.tiny-chat", root)
+    drv = cells.runner(cell)(cell, seed=7)
+    drv.compress()
+    dense = dict(weights.paths(weights.dense(drv.cfg, 7)))
+    whole = drv.check_compression(dense, control=True)
+    assert max(t.num_tiles for t, *_ in drv.compressed.values()) > 100
+    monkeypatch.setitem(type(drv).compress.__globals__, "TILE_BLOCK", 100)
+    blocks = drv.check_compression(dense, control=True)
+    assert blocks == pytest.approx(whole, rel=1e-5, abs=1e-9)
+    assert whole["control_c_gap"] > cell.limits["c_gap"]["limit"] \
+        > whole["c_gap"]
 
 
 def test_best_pair_search_matches_brute_force():

@@ -86,3 +86,47 @@ def test_spin_updates_are_counted_from_the_kernels_operands():
     assert got == pytest.approx(10 * 280 * 24 * 4 * 16 / 5e-3 / 1e6)
     none = trace_reduce.reduce_events({"/device:TPU:0": []}, [], 0, MS)
     assert metric({"trace": none}) is None
+
+
+BITLINEAR = ("%bitlinear_grouped_decode.3 = bf16[32,32,512]{2,1,0:T(8,128)(2,1)}"
+             " custom-call(bf16[32,32,1024]{2,1,0:T(8,128)(2,1)} %x, "
+             "u8[32,32,4,32,1]{4,3,2,1,0:T(1,128)(4,1)} %mp, "
+             "bf16[32,32,4,4,128]{4,3,2,1,0:T(4,128)(2,1)} %C), "
+             'custom_call_target="tpu_custom_call", backend_config="f32[1,2]"')
+
+
+def test_bitlinear_bytes_and_operations_come_from_the_calls_shapes():
+    mod = cells.metric_module(ROOT, "bitlinear_roofline")
+    nbytes, flops = mod.call_cost(BITLINEAR)
+    x, y = 32 * 32 * 1024 * 2, 32 * 32 * 512 * 2
+    m, c = 32 * 32 * 4 * 32, 32 * 32 * 4 * 4 * 128 * 2
+    assert nbytes == x + y + m + c
+    assert flops == 32 * (2 * 32 * 1024 * 4 * 4 + 2 * 32 * 32 * 4 * 4 * 128)
+    assert mod.call_cost(BITLINEAR[:150]) is None         # a cut text
+    ops = {"/device:TPU:0": [(BITLINEAR, i * MS, MS // 10) for i in range(4)]}
+    red = trace_reduce.reduce_events(ops, [], 0, 10 * MS)
+    peaks = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}
+    least = max(nbytes / 819e9, flops / 197e12)
+    got = mod.read({"trace": red, "peaks": peaks})
+    assert got == pytest.approx(100 * 4 * least / (4 * 1e-4))
+    none = trace_reduce.reduce_events({"/device:TPU:0": []}, [], 0, MS)
+    assert mod.read({"trace": none, "peaks": peaks}) is None
+
+
+def test_serve_mfu_counts_the_published_models_operations():
+    mod = cells.metric_module(ROOT, "serve_mfu")
+    conf = {"hidden_size": 8, "num_attention_heads": 2,
+            "num_key_value_heads": 1, "head_dim": 4, "num_hidden_layers": 3,
+            "num_local_experts": 4, "num_experts_per_tok": 2,
+            "intermediate_size": 16, "vocab_size": 10}
+    per_token = 3 * (2 * 8 * (2 * 2 * 4 + 2 * 1 * 4)
+                     + 2 * 8 * 4 + 2 * 3 * 2 * 8 * 16)
+    work = {"tokens": 5, "context": 11, "rows": 2}
+    assert mod.flops(conf, work) == 5 * per_token + 11 * 3 * 4 * 2 * 4 \
+        + 2 * 2 * 8 * 10
+    cell = type("C", (), {"config": conf})
+    red = trace_reduce.reduce_events({"/device:TPU:0": [("a", 0, MS)]}, [],
+                                     0, 2 * MS)
+    got = mod.read({"trace": red, "window": {"work": work}, "cell": cell,
+                    "peaks": {"bf16_flops_per_s": 1e9}})
+    assert got == pytest.approx(100 * mod.flops(conf, work) / 2e-3 / 1e9)
