@@ -26,6 +26,7 @@ ARCHITECTURES = {
     "musicgen-medium": "musicgen_medium",
     "internvl2-2b": "internvl2_2b",
     "zamba2-1.2b": "zamba2_1_2b",
+    "granite-4.0-h-small": "granite_4_0_h_small",
 }
 
 # archs able to run the long_500k cell (sub-quadratic sequence mixing);

@@ -10,6 +10,7 @@ production mesh.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Tuple
 
 __all__ = [
@@ -58,21 +59,40 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0               # 0 -> d_model // num_heads
     # block structure: scan runs over groups of len(block_pattern) layers
-    block_pattern: Tuple[str, ...] = ("attn",)   # attn | attn_moe | ssm | ssm_attn
+    block_pattern: Tuple[str, ...] = ("attn",)   # attn | attn_moe | ssm | ssm_moe | ssm_attn
+    # granitemoehybrid's per-layer mixers ("mamba" | "attention"), each
+    # followed by the MoE FFN; when given, block_pattern is derived from it
+    # and may not be set apart from it
+    layer_types: Tuple[str, ...] = ()
     # attention variants
     qk_norm: bool = False
     use_bias: bool = False
     parallel_block: bool = False    # command-r style parallel attn+mlp
     rope_theta: float = 1e6
+    position_embedding_type: str = "rope"   # rope | nope (no positional signal)
     sliding_window: int = 0         # 0 = full causal; >0 = sliding window
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
+    # granite multipliers, identity by default: embeddings times
+    # embedding_multiplier, attention scores times attention_multiplier
+    # (0 -> 1/sqrt(head_dim)), every residual branch times
+    # residual_multiplier, logits divided by logits_scaling
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # MoE
     num_experts: int = 0
     experts_per_token: int = 0
     moe_shared_expert: bool = False
+    d_ff_shared: int = 0            # shared expert's width (0 -> d_ff)
+    # expert parallelism: this chip holds experts [first_expert_held,
+    # first_expert_held + num_experts_held) of the num_experts the router
+    # scores (0 -> all of them)
+    num_experts_held: int = 0
+    first_expert_held: int = 0
     d_ff_dense: int = 0             # d_ff of non-MoE layers (0 -> d_ff)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25   # 0 -> dropless (models/moe.py)
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
     ssm_headdim: int = 64
@@ -92,9 +112,28 @@ class ModelConfig:
     z_loss: float = 1e-4
     compression: CompressionConfig = CompressionConfig()
 
+    def __post_init__(self):
+        if self.layer_types:
+            kinds = {"mamba": "ssm_moe", "attention": "attn_moe"}
+            # a pattern of these kinds alone is one derived before, which
+            # dataclasses.replace carries along; any other is a conflict
+            if self.block_pattern != ("attn",) and \
+                    not set(self.block_pattern) <= set(kinds.values()):
+                raise ValueError("give layer_types or block_pattern, not both")
+            object.__setattr__(self, "block_pattern",
+                               tuple(kinds[t] for t in self.layer_types))
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
+
+    @property
+    def attention_scale(self) -> float:
+        return self.attention_multiplier or 1.0 / math.sqrt(self.resolved_head_dim)
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts_held or self.num_experts
 
     @property
     def d_inner(self) -> int:  # SSM inner width
@@ -123,12 +162,14 @@ class ModelConfig:
             + (2 * hd if self.qk_norm else 0) \
             + 3 * d * (self.d_ff_dense or ff)
         e = max(self.num_experts, 1)
+        moe = self.experts_held * 3 * d * ff + d * e + d \
+            + (3 * d * (self.d_ff_shared or ff) if self.moe_shared_expert else 0)
         per["attn_moe"] = d * self.num_heads * hd + 2 * d * self.num_kv_heads * hd \
-            + self.num_heads * hd * d + 2 * d + e * 3 * d * ff + d * e \
-            + (3 * d * ff if self.moe_shared_expert else 0)
+            + self.num_heads * hd * d + d + moe
         di, ds, ng, nh = self.d_inner, self.ssm_state, self.ssm_ngroups, self.ssm_nheads
         per["ssm"] = d * (2 * di + 2 * ng * ds + nh) + (di + 2 * ng * ds) * self.ssm_dconv \
             + 3 * nh + di + di * d + d
+        per["ssm_moe"] = per["ssm"] + moe
         per["ssm_attn"] = per["ssm"]  # shared attn params counted once below
         total = counts["embed"] + 2 * d  # final norm (+2d slack)
         for kind in self.block_pattern * self.num_groups + self.remainder_pattern:
@@ -142,10 +183,10 @@ class ModelConfig:
         if self.num_experts == 0:
             return self.param_count()
         d, ff = self.d_model, self.d_ff
-        inactive_per_moe = (self.num_experts - self.experts_per_token) * 3 * d * ff
+        inactive_per_moe = (self.experts_held - self.experts_per_token) * 3 * d * ff
         n_moe = sum(
             1 for k in self.block_pattern * self.num_groups + self.remainder_pattern
-            if k == "attn_moe"
+            if k in ("attn_moe", "ssm_moe")
         )
         return self.param_count() - n_moe * inactive_per_moe
 
@@ -204,6 +245,7 @@ def reduced_for_smoke(cfg: ModelConfig) -> ModelConfig:
         head_dim=16,
         d_ff=128,
         d_ff_dense=128 if cfg.d_ff_dense else 0,
+        d_ff_shared=128 if cfg.d_ff_shared else 0,
         vocab_size=257,
         num_experts=min(cfg.num_experts, 4) if cfg.num_experts else 0,
         experts_per_token=min(cfg.experts_per_token, 2) if cfg.experts_per_token else 0,

@@ -10,7 +10,11 @@ only when window-free causal order allows; otherwise masked in-kernel.
 
 Layouts: q (B, H, S, hd), k/v (B, KV, S, hd) -> out (B, H, S, hd).
 Block sizes default to (512, 512) on the (q, kv) sequence dims; hd is kept
-whole (typically 64/128, MXU-aligned).
+whole (typically 64/128, MXU-aligned).  A sequence longer than a block and
+not a multiple of it is padded at the end to the next multiple: the pad
+keys lie after every real query, so the causal mask hides them, and the
+pad queries' rows are dropped.  Scores are scaled by ``scale``
+(1/sqrt(hd) when not given).
 """
 
 from __future__ import annotations
@@ -72,7 +76,8 @@ def _kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("window", "block_q", "block_k", "interpret")
+    jax.jit,
+    static_argnames=("window", "block_q", "block_k", "interpret", "scale"),
 )
 def flash_attention(
     q: jax.Array,   # (B, H, S, hd)
@@ -82,14 +87,19 @@ def flash_attention(
     block_q: int = 512,
     block_k: int = 512,
     interpret: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
-    B, H, S, hd = q.shape
+    B, H, S0, hd = q.shape
     KV = k.shape[1]
     rep = H // KV
-    qc, kc = min(block_q, S), min(block_k, S)
-    assert S % qc == 0 and S % kc == 0
+    qc, kc = min(block_q, S0), min(block_k, S0)
+    S = -(-S0 // math.lcm(qc, kc)) * math.lcm(qc, kc)
+    if S != S0:
+        pad = [(0, 0), (0, 0), (0, S - S0), (0, 0)]
+        q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
     nq, nk = S // qc, S // kc
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     return pl.pallas_call(
         functools.partial(
@@ -113,4 +123,4 @@ def flash_attention(
         ),
         interpret=interpret,
         name="flash_attention",
-    )(q, k, v)
+    )(q, k, v)[:, :, :S0]

@@ -111,13 +111,13 @@ def enable_kernels(interpret: bool | None = None) -> None:
     """
     it = default_interpret() if interpret is None else interpret
 
-    def _flash_adapter(qh, k, v, window):
+    def _flash_adapter(qh, k, v, window, scale):
         # model layout q (B,S,KV,rep,hd), k/v (B,S,KV,hd)
         B, S, KV, rep, hd = qh.shape
         q = qh.reshape(B, S, KV * rep, hd).transpose(0, 2, 1, 3)
         kk = k.transpose(0, 2, 1, 3)
         vv = v.transpose(0, 2, 1, 3)
-        o = _flash(q, kk, vv, window=window, interpret=it)
+        o = _flash(q, kk, vv, window=window, interpret=it, scale=scale)
         return o.transpose(0, 2, 1, 3).reshape(B, S, KV, rep, hd)
 
     def _fused_bitlinear_adapter(x, w):

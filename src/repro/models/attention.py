@@ -1,4 +1,7 @@
-"""GQA attention with RoPE, optional qk-norm, sliding window, KV cache.
+"""GQA attention with RoPE (or none: ``position_embedding_type="nope"``),
+optional qk-norm, sliding window, KV cache.  Scores are scaled by
+``cfg.attention_scale`` (1/sqrt(head_dim) unless the config sets
+``attention_multiplier``).
 
 Three execution paths:
   * train/prefill: memory-bounded chunked causal attention (online-softmax
@@ -97,6 +100,7 @@ def _chunked_attention(
     window: int,
     q_chunk: int,
     causal_skip: bool = False,
+    scale: float | None = None,
 ) -> jax.Array:
     """Causal (optionally sliding-window) flash-structured attention.
 
@@ -106,7 +110,8 @@ def _chunked_attention(
     dynamic-trip-count loops (§Perf H11 — on TPU the custom-VJP Pallas flash
     kernel is the train-path answer)."""
     B, S, KV, rep, hd = q.shape
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     nq = max(S // q_chunk, 1)
     qc = S // nq
     qs = q.reshape(B, nq, qc, KV, rep, hd)
@@ -159,14 +164,16 @@ def _chunked_attention(
 
 
 def _chunked_attention_unrolled(
-    q: jax.Array, k: jax.Array, v: jax.Array, window: int, q_chunk: int
+    q: jax.Array, k: jax.Array, v: jax.Array, window: int, q_chunk: int,
+    scale: float | None = None,
 ) -> jax.Array:
     """Costing variant (see benchmarks/roofline.py): identical math, but the
     chunk loops are *python-unrolled over the causal lower triangle only*, so
     ``compiled.cost_analysis()`` counts exact causal FLOPs (lax.scan bodies
     are counted once by XLA's cost model, hence this twin)."""
     B, S, KV, rep, hd = q.shape
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     nq = max(S // q_chunk, 1)
     qc = S // nq
     outs = []
@@ -355,8 +362,10 @@ def attention(
         positions = pos_offset[:, None] + jnp.arange(S)   # (B, S)
     else:
         positions = pos_offset + jnp.arange(S)            # (S,)
-    q = _rope(q, positions, cfg.rope_theta)
-    k = _rope(k, positions, cfg.rope_theta)
+    if cfg.position_embedding_type != "nope":
+        q = _rope(q, positions, cfg.rope_theta)
+        k = _rope(k, positions, cfg.rope_theta)
+    scale = cfg.attention_scale
 
     # Ring-buffer cache: a sliding-window attn layer only ever needs the
     # last `window` KV entries, so its cache may be allocated at window size
@@ -410,22 +419,22 @@ def attention(
             if window > 0:
                 valid &= kpos > pb - window
         # valid: (Smax,) scalar pos / (B, Smax) per-slot pos
-        o = _decode_attention(qh, ck, cv, valid, 1.0 / math.sqrt(hd), h.dtype)
+        o = _decode_attention(qh, ck, cv, valid, scale, h.dtype)
         o = o.reshape(B, 1, H * hd)
     elif cache is not None and attend_cache:
         # ---- chunked prefill: chunk queries vs the full updated cache ----
         qh = q.reshape(B, S, KV, rep, hd)
         o = _chunk_cache_attention(
             qh, new_cache["k"], new_cache["v"], positions, window,
-            1.0 / math.sqrt(hd), h.dtype,
+            scale, h.dtype,
         )
         o = o.reshape(B, S, H * hd)
     else:
         qh = q.reshape(B, S, KV, rep, hd)
         if unroll:
-            o = _chunked_attention_unrolled(qh, k, v, window, q_chunk)
+            o = _chunked_attention_unrolled(qh, k, v, window, q_chunk, scale)
         elif _FLASH_IMPL is not None:
-            o = _FLASH_IMPL(qh, k, v, window)
+            o = _FLASH_IMPL(qh, k, v, window, scale)
         else:
             # checkpoint: without it autodiff saves every chunk's fp32 score
             # matrix (2.1 GiB per layer on zamba2 train — §Perf); recomputing
@@ -435,7 +444,7 @@ def attention(
             attn_fn = jax.checkpoint(
                 functools.partial(
                     _chunked_attention, window=window, q_chunk=q_chunk,
-                    causal_skip=cache is not None,
+                    causal_skip=cache is not None, scale=scale,
                 ),
                 policy=jax.checkpoint_policies.nothing_saveable,
             )

@@ -106,11 +106,22 @@ def _ssd_chunk(u, dA_cum, Bm, Cm, S_prev, rep):
 
 def _ssd(u, dA, Bm, Cm, chunk: int, S0, unroll: bool):
     """Full-sequence SSD. u (B,S,nh,hp), dA (B,S,nh) log-decay per step,
-    Bm/Cm (B,S,g,ds). Returns (y, S_final)."""
+    Bm/Cm (B,S,g,ds). Returns (y, S_final).
+
+    A length that no chunk count divides evenly is padded at the end to a
+    multiple of ``chunk``: a pad step has no input (u = 0) and no decay
+    (dA = 0), so it leaves the state as it was, and its outputs are
+    dropped."""
+    B, S0_len, nh, hp = u.shape
+    nc = max(S0_len // chunk, 1)
+    if S0_len % nc:
+        nc = -(-S0_len // chunk)
+        pad = nc * chunk - S0_len
+        padded = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+        u, dA, Bm, Cm = padded(u), padded(dA), padded(Bm), padded(Cm)
     B, S, nh, hp = u.shape
     g = Bm.shape[2]
     rep = nh // g
-    nc = max(S // chunk, 1)
     L = S // nc
     cs = lambda a: a.reshape(B, nc, L, *a.shape[2:])
     uc, dAc, Bc, Cc = cs(u), cs(dA), cs(Bm), cs(Cm)
@@ -122,7 +133,7 @@ def _ssd(u, dA, Bm, Cm, chunk: int, S0, unroll: bool):
         for c in range(nc):
             y, Sst = _ssd_chunk(uc[:, c], dA_cum[:, c], Bc[:, c], Cc[:, c], Sst, rep)
             ys.append(y)
-        return jnp.concatenate(ys, axis=1).reshape(B, S, nh, hp), Sst
+        return jnp.concatenate(ys, axis=1).reshape(B, S, nh, hp)[:, :S0_len], Sst
 
     def step(Sst, xs):
         ucc, dcc, bcc, ccc = xs
@@ -137,7 +148,7 @@ def _ssd(u, dA, Bm, Cm, chunk: int, S0, unroll: bool):
     )
     S_final, ys = jax.lax.scan(step, S0, xs)
     y = ys.transpose(1, 0, 2, 3, 4).reshape(B, S, nh, hp)
-    return y, S_final
+    return y[:, :S0_len], S_final
 
 
 def init_ssm_cache(cfg: ModelConfig, batch: int, dtype) -> dict:

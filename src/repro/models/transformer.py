@@ -2,7 +2,9 @@
 
 A model is a sequence of blocks described by ``cfg.block_pattern`` (e.g.
 ("attn",) for dense LMs, ("attn", "attn_moe") for llama4-style interleaved
-MoE, ("ssm",) for Mamba2, ("ssm",)*5 + ("ssm_attn",) for Zamba2 hybrids).
+MoE, ("ssm",) for Mamba2, ("ssm",)*5 + ("ssm_attn",) for Zamba2 hybrids,
+("ssm_moe",)*5 + ("attn_moe",) + ("ssm_moe",)*4 for granite-4.0-h, whose
+Mamba2 and attention mixers are each followed by the MoE FFN).
 The pattern repeats ``cfg.num_groups`` times under a ``lax.scan`` (stacked
 group parameters -> O(1) compile time in depth) with optional remat;
 leftover layers (num_layers % len(pattern)) run unrolled, and Zamba2's
@@ -66,6 +68,13 @@ def _init_block(key, kind: str, cfg: ModelConfig, dtype) -> dict:
             "norm1": layers.init_rms_norm(d, dtype),
             "ssm": ssm.init_ssm(ks[0], cfg, dtype),
         }
+    if kind == "ssm_moe":
+        return {
+            "norm1": layers.init_rms_norm(d, dtype),
+            "ssm": ssm.init_ssm(ks[0], cfg, dtype),
+            "norm2": layers.init_rms_norm(d, dtype),
+            "moe": moe.init_moe(ks[1], cfg, dtype),
+        }
     raise ValueError(kind)
 
 
@@ -77,6 +86,18 @@ def _init_shared_attn(key, cfg: ModelConfig, dtype) -> dict:
         "norm2": layers.init_rms_norm(cfg.d_model, dtype),
         "mlp": layers.init_mlp(k2, cfg.d_model, cfg.d_ff, dtype, cfg.use_bias),
     }
+
+
+def _residual(h, x, cfg: ModelConfig):
+    """h + residual_multiplier * x (granite scales every residual branch)."""
+    if cfg.residual_multiplier != 1.0:
+        x = x * cfg.residual_multiplier
+    return h + x
+
+
+def _moe_ffn(h, p, cfg: ModelConfig):
+    mo, aux = moe.moe_block(layers.rms_norm(h, p["norm2"], cfg.norm_eps), p["moe"], cfg)
+    return _residual(h, mo, cfg), aux
 
 
 def _apply_block(
@@ -100,24 +121,26 @@ def _apply_block(
             p["attn"], cfg, pos_offset=pos_offset, cache=kv,
             window=window, unroll=unroll, attend_cache=attend_cache,
         )
-        h = h + a
+        h = _residual(h, a, cfg)
         if kind == "attn":
-            h = h + layers.mlp(layers.rms_norm(h, p["norm2"], cfg.norm_eps), p["mlp"])
-        else:
-            mo, aux = moe.moe_block(
-                layers.rms_norm(h, p["norm2"], cfg.norm_eps), p["moe"], cfg
+            h = _residual(
+                h, layers.mlp(layers.rms_norm(h, p["norm2"], cfg.norm_eps), p["mlp"]), cfg
             )
-            h = h + mo
+        else:
+            h, aux = _moe_ffn(h, p, cfg)
         return h, ({"kv": new_kv} if cache is not None else None), aux
 
-    if kind in ("ssm", "ssm_attn"):
+    if kind in ("ssm", "ssm_attn", "ssm_moe"):
         sc = cache["ssm"] if cache is not None else None
-        s, new_sc = ssm.ssm_block(
-            layers.rms_norm(h, p["norm1"], cfg.norm_eps), p["ssm"], cfg,
-            cache=sc, unroll=unroll,
-        )
-        h = h + s
+        with jax.named_scope("ssm.mixer"):
+            s, new_sc = ssm.ssm_block(
+                layers.rms_norm(h, p["norm1"], cfg.norm_eps), p["ssm"], cfg,
+                cache=sc, unroll=unroll,
+            )
+        h = _residual(h, s, cfg)
         new_cache = {"ssm": new_sc} if cache is not None else None
+        if kind == "ssm_moe":
+            h, aux = _moe_ffn(h, p, cfg)
         if kind == "ssm_attn":
             kv = cache["kv"] if cache is not None else None
             a, new_kv = attn_lib.attention(
@@ -125,10 +148,10 @@ def _apply_block(
                 shared["attn"], cfg, pos_offset=pos_offset, cache=kv,
                 window=window, unroll=unroll, attend_cache=attend_cache,
             )
-            h = h + a
-            h = h + layers.mlp(
+            h = _residual(h, a, cfg)
+            h = _residual(h, layers.mlp(
                 layers.rms_norm(h, shared["norm2"], cfg.norm_eps), shared["mlp"]
-            )
+            ), cfg)
             if cache is not None:
                 new_cache["kv"] = new_kv
         return h, new_cache, aux
@@ -215,7 +238,7 @@ def _init_block_cache(kind: str, cfg: ModelConfig, batch: int, max_len: int, dty
     c = {}
     if kind in ("attn", "attn_moe"):
         c["kv"] = attn_lib.init_kv_cache(cfg, batch, max_len, dtype)
-    if kind in ("ssm", "ssm_attn"):
+    if kind in ("ssm", "ssm_attn", "ssm_moe"):
         c["ssm"] = ssm.init_ssm_cache(cfg, batch, dtype)
     if kind == "ssm_attn":
         kv_len = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
@@ -291,6 +314,8 @@ def forward(
         h = layers.embed_lookup(inputs["tokens"], p["embed"]).astype(dtype)
     else:
         h = inputs["embeds"].astype(dtype)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * cfg.embedding_multiplier
     h = constrain(h, "hidden")
 
     window = cfg.sliding_window if window is None else window
@@ -388,6 +413,8 @@ def forward(
         logits = h @ table.T
     else:
         logits = layers.apply_dense(h, p["head"])
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.logits_softcap > 0:
         logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
     logits = constrain(logits, "logits")
@@ -405,6 +432,8 @@ def train_loss(params, batch, cfg: ModelConfig, *, unroll: bool = False):
         head_w = p["embed"]["table"].T
     else:
         head_w = p["head"]["w"]
+    if cfg.logits_scaling != 1.0:
+        head_w = head_w / cfg.logits_scaling
     if "labels" in batch:
         labels = batch["labels"]
         hh = h

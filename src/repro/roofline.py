@@ -178,7 +178,7 @@ def analytic_memory_bytes(cfg, shape, pcfg, chips: int = 256) -> float:
         cache = n_attn * per_seq * max(shape.global_batch // chips, B_loc / mesh_model)
     n_ssm = sum(
         1 for k in cfg.block_pattern * cfg.num_groups + cfg.remainder_pattern
-        if k in ("ssm", "ssm_attn")
+        if k in ("ssm", "ssm_attn", "ssm_moe")
     )
     if n_ssm:
         state = cfg.ssm_nheads * cfg.ssm_headdim * cfg.ssm_state * 4

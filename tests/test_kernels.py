@@ -71,6 +71,30 @@ def test_flash_attention_matches_ref(B, H, KV, S, hd, win, bq, dtype):
     )
 
 
+@pytest.mark.parametrize("S", [558, 939])
+def test_flash_attention_at_any_length_matches_the_jnp_path(S):
+    """A prompt length that is no multiple of the 512 block (the serving
+    mix's 558 and 939) is padded inside the kernel and the pad rows are
+    dropped; the causal mask hides the pad keys.  Against the model's own
+    jnp path at a scale that is not 1/sqrt(hd); both in float32, so only
+    the order of summation differs."""
+    from repro.models.attention import _chunked_attention
+
+    B, KV, rep, hd, scale = 1, 2, 2, 32, 0.0078125
+    ks = jax.random.split(jax.random.PRNGKey(S), 3)
+    q = jax.random.normal(ks[0], (B, S, KV, rep, hd))
+    k = jax.random.normal(ks[1], (B, S, KV, hd))
+    v = jax.random.normal(ks[2], (B, S, KV, hd))
+    o_k = ops.flash_attention(
+        q.reshape(B, S, KV * rep, hd).transpose(0, 2, 1, 3),
+        k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), interpret=True,
+        scale=scale)
+    o_j = _chunked_attention(q, k, v, window=0, q_chunk=512, scale=scale)
+    np.testing.assert_allclose(
+        np.asarray(o_k.transpose(0, 2, 1, 3)),
+        np.asarray(o_j.reshape(B, S, KV * rep, hd)), rtol=2e-5, atol=2e-5)
+
+
 def _rand_problems(key, P, n, scale=0.2):
     from repro.core.ising import random_problems
 

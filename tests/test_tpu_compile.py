@@ -137,7 +137,7 @@ def test_sqa_sweep_many_compiles(one_chip):
     assert _has_kernel(c)
 
 
-@pytest.mark.parametrize("S", [128, 2048])
+@pytest.mark.parametrize("S", [128, 939, 2048])
 def test_flash_attention_compiles(one_chip, S):
     fn = lambda q, k, v: flash_attention(q, k, v, interpret=False)
     c = _compile(fn, one_chip, ((1, 16, S, 64), jnp.bfloat16),
